@@ -6,5 +6,5 @@ import sys
 
 from repro.launch.serve import main
 
-sys.exit(main(["--arch", "qwen2.5-3b", "--requests", "6", "--slots", "3",
-               "--max-new", "8"]))
+sys.exit(main(["--arch", "qwen2.5-3b", "--reduced", "--requests", "6",
+               "--slots", "3", "--max-new", "8"]))

@@ -22,8 +22,7 @@ from typing import Optional
 
 import jax
 
-from repro.roofline.report import (
-    HBM_BW, ICI_LINK_BW, PEAK_FLOPS, flat_cost_analysis)
+from repro.roofline.report import HBM_BW, ICI_LINK_BW, PEAK_FLOPS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +81,7 @@ class CellEvaluationService:
                 compiled = jax.jit(
                     fn, in_shardings=sh, out_shardings=osh,
                     donate_argnums=dn).lower(*args).compile()
-                cost = flat_cost_analysis(compiled)
+                cost = compiled.cost_analysis()
                 mem = compiled.memory_analysis()
                 coll = collective_bytes_from_hlo(compiled.as_text())
         except Exception as e:

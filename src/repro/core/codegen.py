@@ -9,7 +9,10 @@ platform's compile-error feedback.
 Every module defines:
   GENOME : json string (submission metadata; the analytic timing backend
            reads it — the wall-clock backend ignores it)
-  run(a, b, a_scale, b_scale, interpret=True) -> C[bf16]
+  run(a, b, a_scale, b_scale, interpret) -> C[bf16]
+
+The evaluator passes ``interpret`` explicitly (true only on the CPU backend),
+so a kernel is never run in interpret mode on a TPU.
 """
 from __future__ import annotations
 
@@ -28,9 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 calls it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 SCALE_BLOCK = 128
 
 
@@ -45,7 +45,7 @@ def _pad_axis(x, multiple, axis):
 
 _LIBRARY_BODY = '''
 
-def run(a, b, a_scale, b_scale, interpret=True):
+def run(a, b, a_scale, b_scale, interpret):
     """Library path: full f32 dequantization, then one XLA dot."""
     m, k = a.shape
     n = b.shape[1]
@@ -60,7 +60,7 @@ def run(a, b, a_scale, b_scale, interpret=True):
 
 _MONOLITH_BODY = '''
 
-def run(a, b, a_scale, b_scale, interpret=True):
+def run(a, b, a_scale, b_scale, interpret):
     """Single-program kernel: whole problem resident in VMEM (no blocking)."""
     m, k = a.shape
     n = b.shape[1]
@@ -106,14 +106,14 @@ def _body(a_ref, b_ref, as_ref, bs_ref, o_ref, acc_ref, *, k_steps, n_sub,
 
     a = a_ref[...]
     b = b_ref[...]
-    a_s = as_ref[...].astype(jnp.float32)
-    b_s = bs_ref[...].astype(jnp.float32)
+    a_s = as_ref[0]  # (bm, n_sub) f32
+    b_s = bs_ref[0]  # (n_sub, bn) f32, one scale per column
 
     acc = acc_ref[...]
     for s in range(n_sub):
         a_blk = a[:, s * SCALE_BLOCK:(s + 1) * SCALE_BLOCK]
         b_blk = b[s * SCALE_BLOCK:(s + 1) * SCALE_BLOCK, :]
-        b_s_cols = jnp.repeat(b_s[s], SCALE_BLOCK)[None, :]
+        b_s_cols = b_s[s:s + 1, :]
         if SCALE_APPLICATION == "dequant_inputs":
             a_d = (a_blk.astype(jnp.float32) * a_s[:, s:s + 1]).astype(COMPUTE_DTYPE)
             b_d = (b_blk.astype(jnp.float32) * b_s_cols).astype(COMPUTE_DTYPE)
@@ -130,7 +130,7 @@ def _body(a_ref, b_ref, as_ref, bs_ref, o_ref, acc_ref, *, k_steps, n_sub,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype).reshape(o_ref.shape)
 
 
-def run(a, b, a_scale, b_scale, interpret=True):
+def run(a, b, a_scale, b_scale, interpret):
     m_in, k_in = a.shape
     n_in = b.shape[1]
     # clamp blocks to (128-padded) problem dims, then pad to block multiples
@@ -152,6 +152,13 @@ def run(a, b, a_scale, b_scale, interpret=True):
     while gk_total % ks:
         ks -= 1
     gk = gk_total // ks
+    # per-K-step scale blocks whose last two dims meet Mosaic's tiling rule
+    # (full dim or (8, 128) multiple): a (K/bk, M, n_sub), b expanded per
+    # column to (K/bk, n_sub, N)
+    a_scale = a_scale.astype(jnp.float32).reshape(m, gk_total, n_sub)
+    a_scale = a_scale.transpose(1, 0, 2)
+    b_scale = jnp.repeat(b_scale.astype(jnp.float32), SCALE_BLOCK, axis=1)
+    b_scale = b_scale.reshape(gk_total, n_sub, n)
 
     # the K grid axis index depends on the *clamped* split factor
     body = functools.partial(_body, k_steps=gk, n_sub=n_sub,
@@ -174,8 +181,8 @@ def run(a, b, a_scale, b_scale, interpret=True):
     wrap = _imaps(ks > 1)
     imap_a = wrap(lambda i, j, s, kk: (i, s * gk + kk))
     imap_b = wrap(lambda i, j, s, kk: (s * gk + kk, j))
-    imap_as = wrap(lambda i, j, s, kk: (i, s * gk + kk))
-    imap_bs = wrap(lambda i, j, s, kk: (s * gk + kk, j))
+    imap_as = wrap(lambda i, j, s, kk: (s * gk + kk, i, 0))
+    imap_bs = wrap(lambda i, j, s, kk: (s * gk + kk, 0, j))
 
     if ks > 1:
         grid = (gm, gn, ks, gk) if GRID_ORDER == "mn" else (gn, gm, ks, gk)
@@ -186,13 +193,13 @@ def run(a, b, a_scale, b_scale, interpret=True):
             in_specs=[
                 pl.BlockSpec((bm, bk), imap_a),
                 pl.BlockSpec((bk, bn), imap_b),
-                pl.BlockSpec((bm, n_sub), imap_as),
-                pl.BlockSpec((n_sub, bn // SCALE_BLOCK), imap_bs),
+                pl.BlockSpec((1, bm, n_sub), imap_as),
+                pl.BlockSpec((1, n_sub, bn), imap_bs),
             ],
             out_specs=pl.BlockSpec((1, bm, bn), imap_o),
             out_shape=jax.ShapeDtypeStruct((ks, m, n), ACC_DTYPE),
             scratch_shapes=[pltpu.VMEM((bm, bn), ACC_DTYPE)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=tuple(DIMENSION_SEMANTICS[:2])
                 + ("arbitrary", "arbitrary")),
             interpret=interpret,
@@ -207,13 +214,13 @@ def run(a, b, a_scale, b_scale, interpret=True):
             in_specs=[
                 pl.BlockSpec((bm, bk), imap_a),
                 pl.BlockSpec((bk, bn), imap_b),
-                pl.BlockSpec((bm, n_sub), imap_as),
-                pl.BlockSpec((n_sub, bn // SCALE_BLOCK), imap_bs),
+                pl.BlockSpec((1, bm, n_sub), imap_as),
+                pl.BlockSpec((1, n_sub, bn), imap_bs),
             ],
             out_specs=pl.BlockSpec((bm, bn), imap_o),
             out_shape=jax.ShapeDtypeStruct((m, n), OUT_DTYPE),
             scratch_shapes=[pltpu.VMEM((bm, bn), ACC_DTYPE)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=tuple(DIMENSION_SEMANTICS)),
             interpret=interpret,
         )(a, b, a_scale, b_scale)

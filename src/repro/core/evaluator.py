@@ -14,15 +14,18 @@ Reproduces the competition interface constraints exactly:
 
 Two timing backends:
   * ``cost_model`` — analytic TPU-v5e timing from the submission's GENOME
-    metadata (this container has no TPU; the model is the platform).  Its
+    metadata (a formula, never a measurement).  Its
     terms are the §Roofline terms: max(MXU, HBM, VPU) + pipeline overheads.
-  * ``wall_clock`` — really executes the submitted kernel (interpret mode on
-    CPU) and times it; used by tests and examples with small configurations,
-    where it is a true black box.
+  * ``wall_clock`` — really executes the submitted kernel as one jitted
+    program and times it.  ``interpret`` is passed to the submission's
+    ``run`` explicitly and is true only on the CPU backend, so on a TPU the
+    kernel is compiled by Mosaic; on the CPU (tests, small configurations)
+    the timing is of the Pallas interpreter and says nothing about a chip.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import math
 import threading
@@ -211,6 +214,7 @@ class EvaluationService:
         except Exception as e:  # platform 'compile' feedback
             return EvalResult("compile_error", f"{type(e).__name__}: {e}")
 
+        run = self._executable(run)
         ok, err = self._check_correctness(run)
         if err is not None:
             # the kernel compiled/loaded but blew up while executing — a
@@ -278,6 +282,14 @@ class EvaluationService:
         want = np.asarray(ref.scaled_gemm(aq, bq, a_s, b_s), dtype=np.float32)
         self._memo[memo_key] = want
         return want
+
+    @staticmethod
+    def _executable(run):
+        """The submission as the platform runs it: one jitted program, with
+        ``interpret`` fixed by the backend (``kernels.default_interpret``)."""
+        import jax
+        from repro.kernels import default_interpret
+        return jax.jit(functools.partial(run, interpret=default_interpret()))
 
     def _check_correctness(self, run) -> tuple:
         """Returns (is_correct, compile_error_or_None)."""

@@ -144,6 +144,16 @@ def service_spec_of(service) -> dict:
     return fn()
 
 
+def _executes_kernels(spec: dict) -> bool:
+    """Whether a service spec runs submitted kernels: an ``evaluation``
+    service, alone or under fault-injection wrappers."""
+    while spec:
+        if spec.get("kind") == "evaluation":
+            return True
+        spec = spec.get("inner")
+    return False
+
+
 # ---------------------------------------------------------------------------
 # Transports
 # ---------------------------------------------------------------------------
@@ -312,6 +322,13 @@ class SubprocessTransport(WorkerTransport):
         specs = list(specs)
         if not specs:
             raise ValueError("SubprocessTransport needs at least one spec")
+        if any(_executes_kernels(s) for s in specs):
+            import jax
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    "a TPU belongs to one process: subprocess workers cannot "
+                    "run kernels on the chip this process holds; use "
+                    "transport='inprocess'")
         self._specs = specs
         self._policy = policy
         self.heartbeat_interval_s = heartbeat_interval_s

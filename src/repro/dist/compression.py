@@ -24,10 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # moved to jax.experimental.shard_map in 0.4.x
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax
-    from jax import shard_map
 
 POD_AXIS = "pod"
 
@@ -72,8 +68,8 @@ def cross_pod_mean(grads, err, mesh, axis: str = POD_AXIS):
             [o[2] for o in out])
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+        check_vma=False)
     def _mean_ef(g_tree, e_tree):
         gs = treedef.flatten_up_to(g_tree)
         es = treedef.flatten_up_to(e_tree)

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 calls it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import default_interpret
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D); Hq % Hkv == 0 (GQA)."""
     b, hq, s, d = q.shape
@@ -140,10 +139,10 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)
 
 
@@ -209,7 +208,7 @@ def decode_attention(
     *,
     scale: float | None = None,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """q: (B, Hq, D); k, v: (B, Hkv, S, D); kv_len: (B,) int32 valid lengths.
 
@@ -248,9 +247,9 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(kv_len.astype(jnp.int32), qg, k, v)
     return out.reshape(b, hq, d)

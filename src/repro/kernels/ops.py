@@ -53,7 +53,7 @@ def scaled_gemm(
     grid_order="mn",
     scale_application="scale_acc",
     use_pallas=True,
-    interpret=True,
+    interpret=None,
 ):
     if not use_pallas:
         return _ref.scaled_gemm(a, b, a_scale, b_scale)
@@ -87,7 +87,7 @@ def scaled_gemm(
 )
 def attention(
     q, k, v, *, causal=True, window=None, block_q=256, block_k=256,
-    use_pallas=True, interpret=True,
+    use_pallas=True, interpret=None,
 ):
     if not use_pallas:
         return _ref.attention(q, k, v, causal=causal, window=window)
@@ -100,14 +100,14 @@ def attention(
 @functools.partial(
     jax.jit, static_argnames=("block_k", "use_pallas", "interpret")
 )
-def decode_attention(q, k, v, kv_len, *, block_k=512, use_pallas=True, interpret=True):
+def decode_attention(q, k, v, kv_len, *, block_k=512, use_pallas=True, interpret=None):
     if not use_pallas:
         return _ref.decode_attention(q, k, v, kv_len)
     return _fa.decode_attention(q, k, v, kv_len, block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
-def ssd(x, dt, a, b, c, *, d_skip=None, chunk=128, use_pallas=True, interpret=True):
+def ssd(x, dt, a, b, c, *, d_skip=None, chunk=128, use_pallas=True, interpret=None):
     """x: (B, S, H, P), dt: (B, S, H), a: (H,), b/c: (B, S, N)."""
     if not use_pallas:
         return _ref.ssd(x, dt, a, b, c, d_skip=d_skip)

@@ -9,6 +9,9 @@ The TPU-native equivalent implemented here:
   MI300 MFMA 32x32x16 fragments  ->  MXU jnp.dot, preferred_element_type=f32
   LDS tiles + ping/pong          ->  BlockSpec VMEM tiles + pipelined grid
   LDS scale-caching              ->  scale tiles as extra VMEM block operands
+                                     (re-laid out by the wrapper so every
+                                     block's last two dims meet Mosaic's
+                                     (8, 128)-or-full-dim tiling rule)
   wave-cooperative stores        ->  grid-owned output tiles
 
 Every axis the paper's Experiment Designer mutated (tile sizes, layouts,
@@ -25,10 +28,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 calls it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import default_interpret
 
 SCALE_BLOCK = 128
+
+
+def kernel_scale_layouts(a_scale, b_scale, n_sub: int):
+    """Challenge-layout scales -> the per-K-step layouts the kernel reads.
+
+    a_scale (M, K/128) -> (K/block_k, M, n_sub): one K step's block is
+    (block_m, n_sub), whose last dim is the array's full dim.
+    b_scale (K/128, N/128) -> (K/block_k, n_sub, N), expanded per column:
+    one K step's block is (n_sub, block_n), full dim then a lane multiple.
+    """
+    m, kb = a_scale.shape
+    gk = kb // n_sub
+    a_s = a_scale.astype(jnp.float32).reshape(m, gk, n_sub).transpose(1, 0, 2)
+    b_s = jnp.repeat(b_scale.astype(jnp.float32), SCALE_BLOCK, axis=1)
+    return a_s, b_s.reshape(gk, n_sub, -1)
 
 
 def _kernel_body(
@@ -54,15 +71,14 @@ def _kernel_body(
 
     a = a_ref[...]  # (bm, bk) storage dtype
     b = b_ref[...]  # (bk, bn)
-    a_s = as_ref[...].astype(jnp.float32)  # (bm, n_sub)
-    b_s = bs_ref[...].astype(jnp.float32)  # (n_sub, bn // 128)
+    a_s = as_ref[0]  # (bm, n_sub) f32
+    b_s = bs_ref[0]  # (n_sub, bn) f32, one scale per column
 
     acc = acc_ref[...]
     for s in range(n_sub):  # statically unrolled over 128-wide K sub-blocks
         a_blk = a[:, s * SCALE_BLOCK : (s + 1) * SCALE_BLOCK].astype(jnp.float32)
         b_blk = b[s * SCALE_BLOCK : (s + 1) * SCALE_BLOCK, :].astype(jnp.float32)
-        # expand b scales from per-(128x128)-block to per-column
-        b_s_cols = jnp.repeat(b_s[s], SCALE_BLOCK)[None, :]  # (1, bn)
+        b_s_cols = b_s[s : s + 1, :]  # (1, bn)
         if scale_application == "dequant_inputs":
             # scale before the dot: more VPU work, inputs leave exact bf16 grid
             a_blk = (a_blk * a_s[:, s : s + 1]).astype(compute_dtype)
@@ -98,7 +114,7 @@ def scaled_gemm(
     acc_dtype=jnp.float32,
     out_dtype=jnp.bfloat16,
     dimension_semantics=("parallel", "parallel", "arbitrary"),
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: interpret only on the CPU backend
 ):
     """Blocked, scale-fused GEMM.  See module docstring for layout contract.
 
@@ -117,6 +133,7 @@ def scaled_gemm(
     assert block_k % SCALE_BLOCK == 0 and block_n % SCALE_BLOCK == 0
     n_sub = block_k // SCALE_BLOCK
     gm, gn, gk = m // block_m, n // block_n, k // block_k
+    a_scale, b_scale = kernel_scale_layouts(a_scale, b_scale, n_sub)
 
     body = functools.partial(
         _kernel_body,
@@ -132,15 +149,15 @@ def scaled_gemm(
         imap_a = lambda i, j, kk: (i, kk)
         imap_b = lambda i, j, kk: (kk, j)
         imap_o = lambda i, j, kk: (i, j)
-        imap_as = lambda i, j, kk: (i, kk)
-        imap_bs = lambda i, j, kk: (kk, j)
+        imap_as = lambda i, j, kk: (kk, i, 0)
+        imap_bs = lambda i, j, kk: (kk, 0, j)
     else:  # "nm": N outermost — trades A-reload traffic for B-reload traffic
         grid = (gn, gm, gk)
         imap_a = lambda j, i, kk: (i, kk)
         imap_b = lambda j, i, kk: (kk, j)
         imap_o = lambda j, i, kk: (i, j)
-        imap_as = lambda j, i, kk: (i, kk)
-        imap_bs = lambda j, i, kk: (kk, j)
+        imap_as = lambda j, i, kk: (kk, i, 0)
+        imap_bs = lambda j, i, kk: (kk, 0, j)
 
     return pl.pallas_call(
         body,
@@ -148,18 +165,20 @@ def scaled_gemm(
         in_specs=[
             pl.BlockSpec((block_m, block_k), imap_a),
             pl.BlockSpec((block_k, block_n), imap_b),
-            pl.BlockSpec((block_m, n_sub), imap_as),
-            pl.BlockSpec((n_sub, block_n // SCALE_BLOCK), imap_bs),
+            pl.BlockSpec((1, block_m, n_sub), imap_as),
+            pl.BlockSpec((1, n_sub, block_n), imap_bs),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), imap_o),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), acc_dtype)],
-        compiler_params=_CompilerParams(dimension_semantics=dimension_semantics),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=dimension_semantics),
+        interpret=default_interpret() if interpret is None else interpret,
     )(a, b, a_scale, b_scale)
 
 
-def naive_scaled_gemm(a, b, a_scale, b_scale, *, out_dtype=jnp.bfloat16, interpret=True):
+def naive_scaled_gemm(a, b, a_scale, b_scale, *, out_dtype=jnp.bfloat16,
+                      interpret=None):
     """The 'naive HIP translation' seed (paper §3): single grid step, whole
     problem resident, full dequant then one dot.  ~6x slower than the library
     path on MI300; on TPU it simply blows VMEM for real sizes — the cost model
@@ -182,5 +201,5 @@ def naive_scaled_gemm(a, b, a_scale, b_scale, *, out_dtype=jnp.bfloat16, interpr
     return pl.pallas_call(
         body,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(a, b, a_scale, b_scale)

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 calls it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import default_interpret
 
 
 def _ssd_body(dtx_ref, la_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
@@ -64,7 +63,7 @@ def ssd(
     c,
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """dtx: (B, H, S, P); la: (B, H, S); b, c: (B, S, N).  Returns (B, H, S, P)."""
     bsz, h, s, p = dtx.shape
@@ -86,8 +85,8 @@ def ssd(
         out_specs=pl.BlockSpec((1, 1, chunk, p), lambda bb, hh, ic: (bb, hh, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, h, s, p), dtx.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(dtx, la, b, c)

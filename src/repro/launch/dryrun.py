@@ -141,7 +141,7 @@ def _lower_costs(cfg: ArchConfig, shape: ShapeConfig, mesh,
     jfn = jax.jit(fn, in_shardings=shardings, out_shardings=out_shardings,
                   donate_argnums=donate)
     compiled = jfn.lower(*args).compile()
-    cost = report.flat_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     out = {
         "flops": cost.get("flops", 0.0),
         "bytes_accessed": cost.get("bytes accessed", 0.0),

@@ -17,6 +17,7 @@ import dataclasses
 import signal
 import sys
 import time
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
@@ -25,7 +26,10 @@ from repro import configs
 from repro.checkpoint import Checkpointer
 from repro.data import DataConfig, SyntheticLM
 from repro.dist import partition
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import (
+    make_debug_mesh, make_mesh, make_production_mesh, parse_mesh_shape,
+)
 from repro.models import api
 from repro.optim import adamw
 from repro.train import make_train_step
@@ -33,7 +37,14 @@ from repro.train import make_train_step
 EXIT_PREEMPTED = 143
 
 
-def main(argv=None) -> int:
+class TrainResult(NamedTuple):
+    exit_code: int
+    losses: list      # per step run in this process
+    params: Any       # the final (sharded) parameters
+
+
+def run(argv=None) -> TrainResult:
+    """Train as ``argv`` describes; ``main`` is the CLI around it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=configs.ARCH_IDS, default="qwen2.5-3b")
     ap.add_argument("--reduced", action="store_true",
@@ -45,11 +56,16 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--peak-lr", type=float, default=3e-3)
     ap.add_argument("--production-mesh", action="store_true")
+    ap.add_argument("--mesh", default=None, metavar="DxM",
+                    type=parse_mesh_shape,
+                    help="train on a (data, model) mesh over the first D*M "
+                         "devices (default: a debug mesh over all devices)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--d-model", type=int, default=0,
                     help="override width (scaling the reduced config)")
     ap.add_argument("--n-layers", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))
@@ -58,11 +74,17 @@ def main(argv=None) -> int:
     if args.n_layers:
         cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
 
-    mesh = (make_production_mesh() if args.production_mesh
-            else make_debug_mesh())
+    if args.mesh:
+        mesh = make_mesh(args.mesh, ("data", "model"))
+    elif args.production_mesh:
+        mesh = make_production_mesh()
+    else:
+        mesh = make_debug_mesh()
     partition.set_mesh(mesh)
+    dev = mesh.devices.flat[0]
     print(f"mesh: {dict(mesh.shape)}  arch: {cfg.name}  "
-          f"params~{cfg.param_count()/1e6:.1f}M")
+          f"params~{cfg.param_count()/1e6:.1f}M  "
+          f"device: {dev.platform} {dev.device_kind} x{mesh.devices.size}")
 
     data = SyntheticLM(DataConfig(
         vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch,
@@ -127,7 +149,7 @@ def main(argv=None) -> int:
                 ckpt.save(step + 1, {"params": params, "opt": opt_state},
                           extra={"loss": loss, "preempted": True})
             partition.set_mesh(None)
-            return EXIT_PREEMPTED
+            return TrainResult(EXIT_PREEMPTED, losses, params)
 
     if ckpt:
         ckpt.save(args.steps, {"params": params, "opt": opt_state},
@@ -135,7 +157,11 @@ def main(argv=None) -> int:
         ckpt.wait()
     partition.set_mesh(None)
     print(f"final loss {losses[-1]:.4f} (first {losses[0]:.4f})")
-    return 0
+    return TrainResult(0, losses, params)
+
+
+def main(argv=None) -> int:
+    return run(argv).exit_code
 
 
 if __name__ == "__main__":

@@ -8,6 +8,8 @@
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -24,7 +26,10 @@ def _mod(cfg: ArchConfig):
     return _FAMS[cfg.family]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, key):
+    # jitted: eagerly, every per-layer tree and the stack built from it
+    # would sit on the device together, twice the weights at published width
     return _mod(cfg).init_params(cfg, key)
 
 
@@ -38,14 +43,19 @@ def loss_fn(params, cfg: ArchConfig, batch):
     return _mod(cfg).loss_fn(params, cfg, batch)
 
 
+# The embedding tables carry vocab_padded rows; the rows past cfg.vocab are
+# no token, so the logits callers see stop at cfg.vocab.
 def prefill(params, cfg: ArchConfig, batch, max_seq: int):
-    return _mod(cfg).prefill(params, cfg, batch, max_seq)
+    logits, cache = _mod(cfg).prefill(params, cfg, batch, max_seq)
+    return logits[..., :cfg.vocab], cache
 
 
 def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
     if cfg.encoder_only:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
-    return _mod(cfg).decode_step(params, cfg, cache, tokens, positions)
+    logits, cache = _mod(cfg).decode_step(params, cfg, cache, tokens,
+                                          positions)
+    return logits[..., :cfg.vocab], cache
 
 
 def init_cache(cfg: ArchConfig, batch_size: int, max_seq: int,

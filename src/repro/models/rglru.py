@@ -66,17 +66,17 @@ def init_params(cfg: ArchConfig, key):
         "embed": dense_init(kg(), (cfg.vocab_padded, cfg.d_model),
                             in_axis=1, dtype=dtype),
         "ln_f": jnp.zeros((cfg.d_model,), dtype),
-        "super": _stack([
-            {f"{kind}_{i}": _init_block(kg, cfg, kind, dtype)
-             for i, kind in enumerate(pat)} for _ in range(n_super)]),
+        "super": _stack(
+            lambda g: {f"{kind}_{i}": _init_block(g, cfg, kind, dtype)
+                       for i, kind in enumerate(pat)}, kg(), n_super),
     }
     if not cfg.tie_embeddings:
         params["unembed"] = dense_init(kg(), (cfg.vocab_padded, cfg.d_model),
                                        in_axis=1, dtype=dtype)
     if n_tail:
-        params["tail"] = _stack([
-            {f"{pat[i]}_{i}": _init_block(kg, cfg, pat[i], dtype)
-             for i in range(n_tail)}])
+        params["tail"] = _stack(
+            lambda g: {f"{pat[i]}_{i}": _init_block(g, cfg, pat[i], dtype)
+                       for i in range(n_tail)}, kg(), 1)
     return params
 
 

@@ -51,8 +51,8 @@ def init_params(cfg: ArchConfig, key):
         "embed": dense_init(kg(), (cfg.vocab_padded, cfg.d_model),
                             in_axis=1, dtype=dtype),
         "ln_f": jnp.zeros((cfg.d_model,), dtype),
-        "layers": _stack([_init_layer(kg, cfg, dtype)
-                          for _ in range(cfg.n_layers)]),
+        "layers": _stack(lambda g: _init_layer(g, cfg, dtype), kg(),
+                         cfg.n_layers),
     }
     if not cfg.tie_embeddings:
         params["unembed"] = dense_init(kg(), (cfg.vocab_padded, cfg.d_model),

@@ -78,8 +78,10 @@ def _init_layer(kg: KeyGen, cfg: ArchConfig, dtype, *, moe_layer: bool):
     return p
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+def _stack(init_one, key, n: int):
+    """``n`` layers of ``init_one(KeyGen)``, stacked on a leading axis by one
+    vmapped init: tracing ``n`` copies compiles slowly at published depth."""
+    return jax.vmap(lambda k: init_one(KeyGen(k)))(jax.random.split(key, n))
 
 
 def init_params(cfg: ArchConfig, key):
@@ -97,11 +99,11 @@ def init_params(cfg: ArchConfig, key):
     is_moe = cfg.moe is not None
     if n_dense:
         params["dense_layers"] = _stack(
-            [_init_layer(kg, cfg, dtype, moe_layer=False)
-             for _ in range(n_dense)])
+            lambda g: _init_layer(g, cfg, dtype, moe_layer=False),
+            kg(), n_dense)
     params["layers"] = _stack(
-        [_init_layer(kg, cfg, dtype, moe_layer=is_moe)
-         for _ in range(cfg.n_layers - n_dense)])
+        lambda g: _init_layer(g, cfg, dtype, moe_layer=is_moe),
+        kg(), cfg.n_layers - n_dense)
     return params
 
 

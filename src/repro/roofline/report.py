@@ -33,16 +33,6 @@ ICI_LINK_BW = 50e9
 _KEYS = ("flops", "bytes_accessed", "collective_bytes")
 
 
-def flat_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns ``[per-program dict]`` on
-    jax < 0.5 and a single flat dict on newer releases; normalise to the
-    flat dict either way."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def corrected_costs(rec: dict) -> dict:
     """Scan-corrected per-chip costs for a single-pod record.
 

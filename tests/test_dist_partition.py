@@ -2,13 +2,13 @@
 registry.  No subprocesses, no multi-device requirement — spec functions
 only read ``mesh.shape``, so a duck-typed stand-in exercises every
 divisibility branch on the single real CPU device."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import compression, partition
+from repro.launch.mesh import make_mesh
 
 
 class FakeMesh:
@@ -41,14 +41,14 @@ def test_registry_roundtrip():
 
 
 def test_unknown_tag_raises():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     partition.set_mesh(mesh)
     with pytest.raises(ValueError, match="unknown shard tag"):
         partition.shard_named(jnp.ones((4,)), ("X",))
 
 
 def test_tag_arity_must_match_rank():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     partition.set_mesh(mesh)
     with pytest.raises(AssertionError):
         partition.shard_named(jnp.ones((4, 4)), ("D",))
@@ -57,7 +57,7 @@ def test_tag_arity_must_match_rank():
 def test_shard_named_on_real_single_device_mesh():
     """On a trivial concrete mesh every tag resolves to replicated and the
     constraint is still applied (values unchanged)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     partition.set_mesh(mesh)
     x = jnp.arange(32.0).reshape(4, 8)
     y = partition.shard_named(x, ("D", "T"))

@@ -47,7 +47,7 @@ def test_generated_source_is_correct(g):
     aq, a_s = ref.quantize_blockwise(a32)
     bq, b_s = ref.quantize_blockwise_2d(b32)
     want = ref.scaled_gemm(aq, bq, a_s, b_s).astype(jnp.float32)
-    got = np.asarray(run(aq, bq, a_s, b_s), dtype=np.float32)
+    got = np.asarray(run(aq, bq, a_s, b_s, interpret=True), dtype=np.float32)
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(got, np.asarray(want), atol=0.03 * scale)
 
@@ -61,7 +61,7 @@ def test_seed_sources_run():
         aq, a_s = ref.quantize_blockwise(a32)
         bq, b_s = ref.quantize_blockwise_2d(b32)
         want = ref.scaled_gemm(aq, bq, a_s, b_s).astype(jnp.float32)
-        got = np.asarray(run(aq, bq, a_s, b_s), dtype=np.float32)
+        got = np.asarray(run(aq, bq, a_s, b_s, interpret=True), dtype=np.float32)
         scale = float(jnp.max(jnp.abs(want)))
         np.testing.assert_allclose(got, np.asarray(want), atol=0.03 * scale)
 

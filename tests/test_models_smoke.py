@@ -37,7 +37,7 @@ def test_output_shapes(arch):
     batch = api.make_batch(cfg, 2, 64)
     logits, cache = jax.jit(
         lambda p, b: api.prefill(p, cfg, b, 96))(params, batch)
-    assert logits.shape == (2, cfg.vocab_padded)
+    assert logits.shape == (2, cfg.vocab)
     assert np.all(np.isfinite(np.asarray(logits)))
     if cfg.encoder_only:
         assert cache is None

@@ -27,7 +27,8 @@ from repro import configs
 from repro.dist import partition
 from repro.models.config import ShapeConfig
 from repro.launch.dryrun import build_cell
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 partition.set_mesh(mesh)
 for arch in ("qwen3-moe-235b-a22b", "deepseek-v2-236b", "recurrentgemma-9b",
              "mamba2-2.7b", "hubert-xlarge"):
@@ -59,7 +60,8 @@ params = api.init_params(cfg, jax.random.key(0))
 batch = api.make_batch(cfg, 4, 64)
 loss1, _ = jax.jit(lambda p, b: api.loss_fn(p, cfg, b))(params, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 partition.set_mesh(mesh)
 named = lambda tree: jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
                                   is_leaf=lambda x: isinstance(x, P))
@@ -83,7 +85,8 @@ def test_compressed_cross_pod_gradients():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.compression import cross_pod_mean, init_error_state
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("pod", "data"))
 g = {"w": jax.random.normal(jax.random.key(0), (16, 64), jnp.float32)}
 err = init_error_state(g)
 mean, err2 = cross_pod_mean(g, err, mesh)
@@ -106,7 +109,8 @@ def test_autotune_on_debug_mesh():
     out = _run("""
 import jax
 from repro.core.autotune import FrameworkGenome, autotune_cell
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 res = autotune_cell("qwen2.5-3b", "train_4k", budget=3, mesh=mesh,
                     verbose=False)
 assert res["best"]["status"] == "ok", res["best"]

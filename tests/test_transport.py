@@ -423,6 +423,24 @@ def test_make_transport_resolution():
         make_transport("carrier-pigeon", [svc])
 
 
+def test_subprocess_refuses_kernel_workers_on_a_tpu(monkeypatch):
+    """One process owns the chip: kernel-executing subprocess workers are
+    refused there, with a pointer to the in-process transport."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    flaky_eval = FlakyService(EvaluationService(backend="wall_clock"))
+    with pytest.raises(ValueError, match="transport='inprocess'"):
+        make_transport("subprocess", [flaky_eval])
+    # services that run no kernel still get subprocess workers
+    sub = make_transport("subprocess", [EchoService()])
+    assert isinstance(sub, SubprocessTransport)
+    sub.close()
+    pool = EvalPool.of(EvaluationService(backend="wall_clock"), workers=1,
+                       transport="inprocess")
+    assert isinstance(pool.transport, InProcessTransport)
+    pool.close()
+
+
 # ---------------------------------------------------------------------------
 # The backend= constructor surface + deprecated shims
 # ---------------------------------------------------------------------------
