@@ -336,8 +336,9 @@ def chunked_softmax_xent(x, emb_out, labels, weights=None, chunk: int = 8192,
     def body(carry, inp):
         nll_sum, w_sum = carry
         xb, lb, wb = inp
-        logits = jnp.einsum("td,vd->tv", xb, emb_out,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("td,vd->tv", xb, emb_out,
+                                preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
         nll = (lse - gold) * wb

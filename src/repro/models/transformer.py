@@ -168,8 +168,9 @@ def attention_decode(p, x, positions, cfg: ArchConfig, cache_k, cache_v,
             lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
                 c, n[None], i, axis=0))(cache, new, kv_len)
 
-    cache_k = upd(cache_k, k)                 # (B, Smax, Hkv, dh)
-    cache_v = upd(cache_v, v)
+    with jax.named_scope("kv_cache"):
+        cache_k = upd(cache_k, k)             # (B, Smax, Hkv, dh)
+        cache_v = upd(cache_v, v)
     y = decode_attention_xla(
         q, cache_k.transpose(0, 2, 1, 3), cache_v.transpose(0, 2, 1, 3),
         kv_len + 1)
@@ -240,8 +241,9 @@ def mla_decode(p, x, positions, cfg: ArchConfig, cache_ckv, cache_kr, kv_len):
             lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
                 c, n[None], i, axis=0))(cache, new, kv_len)
 
-    cache_ckv = upd(cache_ckv, ckv)
-    cache_kr = upd(cache_kr, kr)
+    with jax.named_scope("kv_cache"):
+        cache_ckv = upd(cache_ckv, ckv)
+        cache_kr = upd(cache_kr, kr)
 
     wk_b = p["wk_b"].reshape(m.kv_lora, h, m.d_nope)
     q_lat = jnp.einsum("bhn,lhn->bhl", qn, wk_b)             # absorb W_uk
@@ -271,36 +273,42 @@ def ffn_dense(p, x):
 
 
 def _layer_seq(lp, x, positions, cfg: ArchConfig, kv_len=None):
-    y, kv = attention_seq(lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
-                          positions, cfg, kv_len=kv_len)
+    with jax.named_scope("attention"):
+        y, kv = attention_seq(lp["attn"],
+                              rms_norm(x, lp["ln1"], cfg.norm_eps),
+                              positions, cfg, kv_len=kv_len)
     x = x + y
-    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if "moe" in lp:
-        b, s, d = h.shape
-        y, aux = moe_ffn(lp["moe"], h.reshape(b * s, d), cfg.moe,
-                         norm_topk=cfg.moe.n_shared == 0)
-        y = y.reshape(b, s, d)
-    else:
-        y, aux = ffn_dense(lp["ffn"], h), {"moe_aux": jnp.zeros((), jnp.float32),
-                                           "moe_z": jnp.zeros((), jnp.float32)}
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if "moe" in lp:
+            b, s, d = h.shape
+            y, aux = moe_ffn(lp["moe"], h.reshape(b * s, d), cfg.moe,
+                             norm_topk=cfg.moe.n_shared == 0)
+            y = y.reshape(b, s, d)
+        else:
+            y, aux = ffn_dense(lp["ffn"], h), {
+                "moe_aux": jnp.zeros((), jnp.float32),
+                "moe_z": jnp.zeros((), jnp.float32)}
     return x + y, aux, kv
 
 
 def _layer_decode(lp, x, positions, cfg: ArchConfig, cache, kv_len):
-    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    if cfg.family == "mla":
-        y, c0, c1 = mla_decode(lp["attn"], h, positions, cfg,
-                               cache[0], cache[1], kv_len)
-    else:
-        y, c0, c1 = attention_decode(lp["attn"], h, positions, cfg,
-                                     cache[0], cache[1], kv_len)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        if cfg.family == "mla":
+            y, c0, c1 = mla_decode(lp["attn"], h, positions, cfg,
+                                   cache[0], cache[1], kv_len)
+        else:
+            y, c0, c1 = attention_decode(lp["attn"], h, positions, cfg,
+                                         cache[0], cache[1], kv_len)
     x = x + y
-    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if "moe" in lp:
-        y, _ = moe_ffn(lp["moe"], h, cfg.moe,
-                       norm_topk=cfg.moe.n_shared == 0)
-    else:
-        y = ffn_dense(lp["ffn"], h)
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if "moe" in lp:
+            y, _ = moe_ffn(lp["moe"], h, cfg.moe,
+                           norm_topk=cfg.moe.n_shared == 0)
+        else:
+            y = ffn_dense(lp["ffn"], h)
     return x + y, (c0, c1)
 
 
@@ -310,7 +318,8 @@ def _layer_decode(lp, x, positions, cfg: ArchConfig, cache, kv_len):
 def _embed_in(params, cfg: ArchConfig, batch):
     if cfg.inputs == "embeddings":
         return batch["embeds"]
-    return params["embed"][batch["tokens"]]
+    with jax.named_scope("embed"):
+        return params["embed"][batch["tokens"]]
 
 
 def _positions(cfg: ArchConfig, batch, b, s):
@@ -370,9 +379,10 @@ def loss_fn(params, cfg: ArchConfig, batch):
     weights = batch.get("loss_weights")
     if weights is not None:
         weights = weights.reshape(b * s)
-    nll, denom = chunked_softmax_xent(
-        hidden.reshape(b * s, d), unembed, labels, weights,
-        chunk=cfg.loss_chunk, unroll=cfg.exact_count)
+    with jax.named_scope("loss"):           # holds the head's "head" scope
+        nll, denom = chunked_softmax_xent(
+            hidden.reshape(b * s, d), unembed, labels, weights,
+            chunk=cfg.loss_chunk, unroll=cfg.exact_count)
     loss = nll / jnp.maximum(denom, 1.0)
     total = loss + 1e-2 * aux["moe_aux"] + 1e-3 * aux["moe_z"]
     return total, {"nll": loss, "moe_aux": aux["moe_aux"],
@@ -403,25 +413,27 @@ def prefill(params, cfg: ArchConfig, batch, max_seq: int):
     b, s, d = hidden.shape
     unembed = params.get("unembed", params["embed"])
     last = hidden[:, -1, :]
-    logits = jnp.einsum("bd,vd->bv", last, unembed,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bd,vd->bv", last, unembed,
+                            preferred_element_type=jnp.float32)
     if cfg.encoder_only:
         return logits, None
     pad = max_seq - s
-    if cfg.family == "mla":
-        ckv, kr = kv
-        cache = {
-            "ckv": jnp.pad(ckv, ((0, 0), (0, 0), (0, pad), (0, 0))),
-            "kr": jnp.pad(kr, ((0, 0), (0, 0), (0, pad), (0, 0))),
-            "len": jnp.full((b,), s, jnp.int32),
-        }
-    else:
-        k, v = kv  # (L, B, Hkv, S, dh) -> (L, B, S, Hkv, dh)
-        k = jnp.pad(k.transpose(0, 1, 3, 2, 4),
-                    ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v.transpose(0, 1, 3, 2, 4),
-                    ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        cache = {"k": k, "v": v, "len": jnp.full((b,), s, jnp.int32)}
+    with jax.named_scope("kv_cache"):
+        if cfg.family == "mla":
+            ckv, kr = kv
+            cache = {
+                "ckv": jnp.pad(ckv, ((0, 0), (0, 0), (0, pad), (0, 0))),
+                "kr": jnp.pad(kr, ((0, 0), (0, 0), (0, pad), (0, 0))),
+                "len": jnp.full((b,), s, jnp.int32),
+            }
+        else:
+            k, v = kv  # (L, B, Hkv, S, dh) -> (L, B, S, Hkv, dh)
+            k = jnp.pad(k.transpose(0, 1, 3, 2, 4),
+                        ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(v.transpose(0, 1, 3, 2, 4),
+                        ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            cache = {"k": k, "v": v, "len": jnp.full((b,), s, jnp.int32)}
     return logits, cache
 
 
@@ -431,7 +443,8 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
     if cfg.inputs == "embeddings" and tokens.ndim == 2:
         x = tokens
     else:
-        x = params["embed"][tokens]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
     kv_len = cache["len"]
     b = x.shape[0]
     if positions is None:
@@ -467,8 +480,9 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
 
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed", params["embed"])
-    logits = jnp.einsum("bd,vd->bv", x, unembed,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bd,vd->bv", x, unembed,
+                            preferred_element_type=jnp.float32)
     if len(new_caches) == 2:
         n0 = jax.tree.map(lambda a, c: jnp.concatenate([a, c], 0),
                           new_caches[0], new_caches[1])

@@ -6,10 +6,19 @@ every engine tick runs one fused decode step across all active slots;
 finished requests free their slot immediately (no head-of-line blocking).
 This is the serving analogue of the paper's evaluation loop — sequential
 admission, batched execution.
+
+The device programs are named ``jit_prefill``, ``jit_write_slot`` and
+``jit_decode_step``.  Each step of the host loop runs in a ``repro.*`` span
+(``repro.obs``): ``admit`` (``rid``, ``slot``, ``tokens``) around one
+request's admission, holding ``prefill`` (``rid``, ``tokens``),
+``write_slot`` (``rid``, ``slot``) and ``first_token`` (``rid``); then per
+tick ``decode`` (``live``), ``sample`` (``live``) and ``retire``
+(``finished``).  A request's ``t_admit`` is when its admission began.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Optional
 
@@ -21,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.dist import partition
 from repro.models import api
 from repro.models.config import ArchConfig
+from repro.obs import span
 
 
 def _named(tree, mesh):
@@ -36,6 +46,7 @@ class Request:
     eos_id: Optional[int] = None
     generated: list = dataclasses.field(default_factory=list)
     slot: Optional[int] = None
+    t_admit: Optional[float] = None  # time.perf_counter(): admission began
 
     @property
     def done(self) -> bool:
@@ -47,6 +58,14 @@ class Request:
 
 def _batch_axis(key: str) -> int:
     return 0 if key == "len" else 1
+
+
+def write_slot(cache, cache1, slot):
+    """``cache`` with slot ``slot`` (traced) replaced by the one-slot
+    ``cache1``: a new copy of each array, as ``.at[].set`` makes."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(
+                v, cache1[k].astype(v.dtype), slot, _batch_axis(k))
+            for k, v in cache.items()}
 
 
 class Engine:
@@ -77,10 +96,16 @@ class Engine:
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
 
-        self._prefill = jax.jit(
-            lambda p, b: api.prefill(p, cfg, b, max_seq))
-        self._decode = jax.jit(
-            lambda p, c, t: api.decode_step(p, cfg, c, t))
+        # named functions: the programs are jit_prefill, jit_decode_step
+        def prefill(p, b):
+            return api.prefill(p, cfg, b, max_seq)
+
+        def decode_step(p, c, t):
+            return api.decode_step(p, cfg, c, t)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
+        self._write_slot = jax.jit(write_slot)
 
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> None:
@@ -91,30 +116,26 @@ class Engine:
             req = self.queue.popleft()
             slot = self.free.popleft()
             req.slot = slot
-            s = len(req.prompt)
-            pad = -s % self.prefill_pad
-            toks = np.pad(req.prompt, (0, pad))
-            batch = {"tokens": jnp.asarray(toks, jnp.int32)[None]}
-            if self.cfg.mrope:
-                pos = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]
-                batch["positions"] = jnp.stack([pos, pos * 0, pos * 0], 0)
-            logits, cache1 = self._prefill(self.params, batch)
-            cache1 = dict(cache1)
-            cache1["len"] = jnp.full((1,), s + pad, jnp.int32)
-            self._write_slot(slot, cache1)
-            if pad == 0:   # last-position logits are the first new token
-                req.generated.append(int(jnp.argmax(logits[0])))
-            self.active[slot] = req
-
-    def _write_slot(self, slot: int, cache1) -> None:
-        def put(dst, src, key):
-            ax = _batch_axis(key)
-            idx = [slice(None)] * dst.ndim
-            idx[ax] = slice(slot, slot + 1)
-            return dst.at[tuple(idx)].set(src)
-
-        self.cache = {k: put(self.cache[k], cache1[k], k)
-                      for k in self.cache}
+            req.t_admit = time.perf_counter()
+            with span("admit", rid=req.rid, slot=slot,
+                      tokens=len(req.prompt)):
+                pad = -len(req.prompt) % self.prefill_pad
+                toks = np.pad(req.prompt, (0, pad))
+                with span("prefill", rid=req.rid, tokens=len(toks)):
+                    batch = {"tokens": jnp.asarray(toks, jnp.int32)[None]}
+                    if self.cfg.mrope:
+                        pos = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]
+                        batch["positions"] = jnp.stack(
+                            [pos, pos * 0, pos * 0], 0)
+                    # the prefill's cache holds its length, pad included
+                    logits, cache1 = self._prefill(self.params, batch)
+                with span("write_slot", rid=req.rid, slot=slot):
+                    self.cache = self._write_slot(self.cache, cache1,
+                                                  np.int32(slot))
+                if pad == 0:   # last-position logits are the first new token
+                    with span("first_token", rid=req.rid):
+                        req.generated.append(int(jnp.argmax(logits[0])))
+                self.active[slot] = req
 
     # --------------------------------------------------------------- tick
     def tick(self) -> int:
@@ -122,20 +143,27 @@ class Engine:
         self._admit()
         if not self.active:
             return 0
-        tokens = np.zeros((self.slots,), np.int32)
-        for slot, req in self.active.items():
-            tokens[slot] = (req.generated[-1] if req.generated
-                            else req.prompt[-1])
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for slot in list(self.active):
-            req = self.active[slot]
-            req.generated.append(int(nxt[slot]))
-            if req.done:
-                del self.active[slot]
-                self.free.append(slot)
-                self.finished.append(req)
+        live = len(self.active)
+        with span("decode", live=live):
+            tokens = np.zeros((self.slots,), np.int32)
+            for slot, req in self.active.items():
+                tokens[slot] = (req.generated[-1] if req.generated
+                                else req.prompt[-1])
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(tokens))
+        with span("sample", live=live):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("retire") as s:
+            done = 0
+            for slot in list(self.active):
+                req = self.active[slot]
+                req.generated.append(int(nxt[slot]))
+                if req.done:
+                    del self.active[slot]
+                    self.free.append(slot)
+                    self.finished.append(req)
+                    done += 1
+            s.set_metadata(finished=done)
         return len(self.active)
 
     def run(self, max_ticks: int = 1000) -> list:

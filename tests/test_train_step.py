@@ -1,10 +1,12 @@
 """Train-step semantics: microbatch accumulation parity, donation safety,
 deterministic resume math."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import configs
 from repro.models import api
@@ -59,3 +61,37 @@ def test_two_steps_deterministic():
     pb, ob, _ = step(params2, opt2, batch2, jnp.int32(0))
     for a, b in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _scopes_in(compiled_text: str) -> set:
+    """The named scopes in a compiled program's op names, with JAX's
+    transformation wrappers taken off: "transpose(jvp(loss))" -> loss."""
+    out = set()
+    for path in re.findall(r'op_name="([^"]*)"', compiled_text):
+        for part in path.split("/"):
+            while (m := re.match(r"^[\w.-]+\((.*)\)$", part)):
+                part = m.group(1)
+            out.add(part)
+    return out
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("train", {"embed", "attention", "ffn", "head", "loss", "adamw"}),
+    ("prefill", {"embed", "attention", "kv_cache", "ffn", "head"}),
+    ("decode", {"embed", "attention", "kv_cache", "ffn", "head"}),
+])
+def test_named_scopes_reach_the_compiled_programs(program, scopes):
+    cfg = configs.get_reduced("qwen2.5-3b")
+    params = api.abstract_params(cfg)
+    batch = jax.eval_shape(lambda: api.make_batch(cfg, 2, 32))
+    if program == "train":
+        opt = jax.eval_shape(adamw.init, params)
+        low = jax.jit(make_train_step(cfg)).lower(params, opt, batch,
+                                                  jnp.int32(0))
+    elif program == "prefill":
+        low = jax.jit(make_prefill_step(cfg, 64)).lower(params, batch)
+    else:
+        cache = jax.eval_shape(lambda: api.init_cache(cfg, 2, 64))
+        low = jax.jit(lambda p, c, t: api.decode_step(p, cfg, c, t)).lower(
+            params, cache, jax.ShapeDtypeStruct((2,), jnp.int32))
+    assert scopes <= _scopes_in(low.compile().as_text())
