@@ -5,6 +5,7 @@
     prefill(params, cfg, batch, max_seq)   -> (last-token logits, cache)
     decode_step(params, cfg, cache, toks)  -> (logits, new cache)
     init_cache(cfg, batch, max_seq)        -> zeroed cache pytree
+    attn_steps(cfg, seq_len)               -> flash-loop steps per layer
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from . import rglru, ssm, transformer
+from .common import attention_plan
 from .config import ArchConfig
 
 _FAMS = {
@@ -61,6 +63,17 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
 def init_cache(cfg: ArchConfig, batch_size: int, max_seq: int,
                dtype=jnp.bfloat16):
     return _mod(cfg).init_cache(cfg, batch_size, max_seq, dtype)
+
+
+def attn_steps(cfg: ArchConfig, seq_len: int) -> int:
+    """Serial flash-loop steps of one attention layer over ``seq_len``
+    tokens, as `blockwise_attention` plans them (0: no attention)."""
+    if cfg.family == "ssm":
+        return 0
+    window = cfg.rglru.window if cfg.family == "rglru" else None
+    return attention_plan(seq_len, seq_len, cfg.attn_q_chunk,
+                          cfg.attn_k_chunk, causal=not cfg.encoder_only,
+                          window=window).steps
 
 
 def make_batch(cfg: ArchConfig, batch_size: int, seq_len: int, key=None):

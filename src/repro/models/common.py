@@ -8,7 +8,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -257,6 +257,48 @@ def _make_blockwise(b, h, sq, sk, d, dv, q_chunk, k_chunk, scale,
     return attn
 
 
+class AttentionPlan(NamedTuple):
+    """How `blockwise_attention` tiles one call: the lengths it runs
+    (padded), its chunks, and its serial flash-loop steps (visible pairs)."""
+    sq: int
+    sk: int
+    q_chunk: int
+    k_chunk: int
+    steps: int
+
+
+def _run_len(s: int, chunk: int, t: int) -> int:
+    """``s``, or the next multiple of ``t`` where the largest chunk that
+    divides ``s`` would fall below ``t`` (a length past ``chunk`` with a
+    small factor of two, e.g. 1056 under 512 gives 32)."""
+    if s <= chunk or math.gcd(chunk, s) >= t:
+        return s
+    return -(-s // t) * t
+
+
+@functools.lru_cache(maxsize=1024)
+def attention_plan(sq: int, sk: int, q_chunk: int, k_chunk: int, *,
+                   causal: bool = True, window: Optional[int] = None,
+                   q_offset: int = 0) -> AttentionPlan:
+    """Tiles of at least ``t = min(q_chunk, k_chunk, 256)``: each length is
+    padded to a multiple of ``t`` where its divisor chunk would be smaller;
+    the chunks are then the largest that divide the (padded) lengths.
+    (On a TPU v5e, qwen2.5-3b's prefill of 1056–1632 tokens ran 9–19%
+    faster with 256 than with 128: each serial step has a fixed cost.)"""
+    t = min(q_chunk, k_chunk, 256)
+    sq, sk = _run_len(sq, q_chunk, t), _run_len(sk, k_chunk, t)
+    q_chunk = math.gcd(min(q_chunk, sq), sq)   # largest dividing chunk
+    k_chunk = math.gcd(min(k_chunk, sk), sk)
+    steps = len(_visible_pairs(sq // q_chunk, sk // k_chunk, q_chunk,
+                               k_chunk, causal, window, q_offset))
+    return AttentionPlan(sq, sk, q_chunk, k_chunk, steps)
+
+
+def _pad_seq(x, s: int):
+    return x if x.shape[2] == s else jnp.pad(
+        x, ((0, 0), (0, 0), (0, s - x.shape[2]), (0, 0)))
+
+
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None,
                         q_chunk: int = 512, k_chunk: int = 1024,
@@ -266,6 +308,10 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     kv_len: optional (B,) valid KV prefix lengths (cached decode/prefill).
     q_offset: absolute position of q[0] relative to the KV sequence.
+
+    Tiles follow `attention_plan`.  Padded queries are sliced off the
+    output; padded keys come after every real query, so a causal mask hides
+    them, and otherwise they are masked as keys past ``kv_len``.
     """
     from repro.dist import partition as _dist
 
@@ -273,8 +319,14 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     hkv, sk = k.shape[1], k.shape[2]
     dv = v.shape[-1]                 # may differ from d (MLA)
     g = hq // hkv
-    q_chunk = math.gcd(min(q_chunk, sq), sq)   # largest dividing chunk
-    k_chunk = math.gcd(min(k_chunk, sk), sk)
+    plan = attention_plan(sq, sk, q_chunk, k_chunk, causal=causal,
+                          window=window, q_offset=q_offset)
+    if plan.sk > sk and kv_len is None and not (causal
+                                                and q_offset + sq <= sk):
+        kv_len = jnp.full((b,), sk, jnp.int32)
+    q = _pad_seq(q, plan.sq)
+    k, v = _pad_seq(k, plan.sk), _pad_seq(v, plan.sk)
+    q_chunk, k_chunk = plan.q_chunk, plan.k_chunk
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
     # query-head sharding over 'model'; expand KV to query heads so every
@@ -288,10 +340,12 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     k = _dist.shard_named(k, ("D", "T", "-", "-"))
     v = _dist.shard_named(v, ("D", "T", "-", "-"))
 
-    attn = _make_blockwise(b, hq, sq, sk, d, dv, q_chunk, k_chunk,
+    attn = _make_blockwise(b, hq, plan.sq, plan.sk, d, dv, q_chunk, k_chunk,
                            float(scale), causal, window, q_offset,
                            kv_len is not None, str(q.dtype), unroll)
     out = attn(q, k, v, kv_len)
+    if plan.sq > sq:
+        out = out[:, :, :sq]
     return _dist.shard_named(out, ("D", "T", "-", "-"))
 
 

@@ -10,7 +10,8 @@ admission, batched execution.
 The device programs are named ``jit_prefill``, ``jit_write_slot`` and
 ``jit_decode_step``.  Each step of the host loop runs in a ``repro.*`` span
 (``repro.obs``): ``admit`` (``rid``, ``slot``, ``tokens``) around one
-request's admission, holding ``prefill`` (``rid``, ``tokens``),
+request's admission, holding ``prefill`` (``rid``, ``tokens``,
+``attn_steps``: the flash-loop steps per attention layer),
 ``write_slot`` (``rid``, ``slot``) and ``first_token`` (``rid``); then per
 tick ``decode`` (``live``), ``sample`` (``live``) and ``retire``
 (``finished``).  A request's ``t_admit`` is when its admission began.
@@ -121,7 +122,8 @@ class Engine:
                       tokens=len(req.prompt)):
                 pad = -len(req.prompt) % self.prefill_pad
                 toks = np.pad(req.prompt, (0, pad))
-                with span("prefill", rid=req.rid, tokens=len(toks)):
+                with span("prefill", rid=req.rid, tokens=len(toks),
+                          attn_steps=api.attn_steps(self.cfg, len(toks))):
                     batch = {"tokens": jnp.asarray(toks, jnp.int32)[None]}
                     if self.cfg.mrope:
                         pos = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]

@@ -100,7 +100,8 @@ def test_engine_spans_reach_the_profiler_trace(tmp_path):
     _, a0, a1, _ = admits[0]
     inside = [(n, st) for n, s, e, st in second if a0 <= s and e <= a1
               and n != "repro.admit"]
-    assert inside == [("repro.prefill", {"rid": 11, "tokens": 5}),
+    assert inside == [("repro.prefill", {"rid": 11, "tokens": 5,
+                                         "attn_steps": 1}),
                       ("repro.write_slot", {"rid": 11, "slot": 0}),
                       ("repro.first_token", {"rid": 11})]
     lives = [st["live"] for n, _, _, st in events if n == "repro.decode"]
