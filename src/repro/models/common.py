@@ -349,24 +349,49 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     return _dist.shard_named(out, ("D", "T", "-", "-"))
 
 
-def decode_attention_xla(q, k, v, kv_len, *, scale=None, window=None):
-    """One new token vs. a cache.  q: (B, Hq, D); k, v: (B, Hkv, S, D);
-    kv_len: (B,) — the new token sits at position kv_len - 1."""
+def decode_softmax(logits, kv_len, logit_new=None):
+    """Float32 softmax of one token's scores against a cache: ``logits``
+    (B, ..., S) over the cached positions, those ``>= kv_len`` (B,) masked,
+    and, given ``logit_new`` (B, ...), the token's score against its own key
+    as one more position.  Returns (probabilities (B, ..., S), the token's
+    own (B, ...) or None): the same numbers as writing the token's key at
+    ``kv_len`` first and attending to ``kv_len + 1`` positions."""
+    s = logits.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (kv_len.shape[0], s), 1)
+    valid = (pos < kv_len[:, None]).reshape(
+        (kv_len.shape[0],) + (1,) * (logits.ndim - 2) + (s,))
+    logits = jnp.where(valid, logits.astype(jnp.float32), NEG_INF)
+    if logit_new is None:
+        return jax.nn.softmax(logits, axis=-1), None
+    logit_new = logit_new.astype(jnp.float32)
+    top = jnp.maximum(logits.max(axis=-1), logit_new)
+    e = jnp.exp(logits - top[..., None])
+    e_new = jnp.exp(logit_new - top)
+    total = e.sum(axis=-1) + e_new
+    return e / total[..., None], e_new / total
+
+
+def decode_attention_xla(q, k, v, kv_len, *, new=None, scale=None):
+    """One token against a cache in the cache's own layout, so no slab is
+    transposed.  q: (B, Hq, D); k, v: (B, S, Hkv, D); the first ``kv_len``
+    (B,) positions are attended.  ``new`` = (k, v) of the token itself,
+    (B, Hkv, D) each, joins the same softmax (`decode_softmax`), so the
+    caller can write it into the cache after reading it."""
     b, hq, d = q.shape
-    hkv, s = k.shape[1], k.shape[2]
+    hkv = k.shape[2]
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     qf = q.reshape(b, hkv, g, d) * scale
-    logits = jnp.einsum("bhgd,bhtd->bhgt", qf, k,
+    logits = jnp.einsum("bhgd,bthd->bhgt", qf, k,
                         preferred_element_type=jnp.float32)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-    valid = pos < kv_len[:, None]
-    if window is not None:
-        valid &= pos > (kv_len[:, None] - 1 - window)
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhgt,bhtd->bhgd", probs, v,
+    logit_new = None if new is None else jnp.einsum(
+        "bhgd,bhd->bhg", qf, new[0], preferred_element_type=jnp.float32)
+    probs, p_new = decode_softmax(logits, kv_len, logit_new)
+    out = jnp.einsum("bhgt,bthd->bhgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    if new is not None:
+        out += (p_new.astype(v.dtype).astype(jnp.float32)[..., None]
+                * new[1][:, :, None, :].astype(jnp.float32))
     return out.reshape(b, hq, d).astype(q.dtype)
 
 

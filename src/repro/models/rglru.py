@@ -347,8 +347,7 @@ def _decode_block(bp, kind, x, cache_rows, kv_len, window, cfg: ArchConfig):
             c, n[None], i, axis=0))
         ck, cv = upd(ck, k, slot), upd(cv, v, slot)
         n_valid = jnp.minimum(kv_len + 1, window)
-        y = decode_attention_xla(q, ck.transpose(0, 2, 1, 3),
-                                 cv.transpose(0, 2, 1, 3), n_valid)
+        y = decode_attention_xla(q, ck, cv, n_valid)
         y = (y.reshape(b, hh * dh)) @ p["wo"]
         new_rows = (ck, cv)
     else:

@@ -18,7 +18,7 @@ from repro.dist import partition as _dist
 
 from .common import (
     KeyGen, apply_mrope, apply_rope, blockwise_attention, chunked_softmax_xent,
-    decode_attention_xla, dense_init, rms_norm,
+    decode_attention_xla, decode_softmax, dense_init, rms_norm,
 )
 from .config import ArchConfig
 from .moe import init_moe_ffn, moe_ffn
@@ -145,8 +145,10 @@ def attention_seq(p, x, positions, cfg: ArchConfig, *, kv_len=None):
 
 def attention_decode(p, x, positions, cfg: ArchConfig, cache_k, cache_v,
                      kv_len):
-    """x: (B, D) one token; cache_k/v: (B, Smax, Hkv, dh); writes at kv_len.
-    Returns (y, new_k_cache, new_v_cache)."""
+    """x: (B, D) one token at position kv_len; cache_k/v: (B, Smax, Hkv, dh),
+    read only.  The token attends to the cached [0, kv_len) and to itself.
+    Returns (y, k, v): its own K/V (B, Hkv, dh), for the caller to write at
+    kv_len."""
     b, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     q = (x @ p["wq"])
@@ -162,20 +164,10 @@ def attention_decode(p, x, positions, cfg: ArchConfig, cache_k, cache_v,
               else pos[:, None])[:, :, 0, :]
     k = _rope(cfg, k[:, :, None, :], pos[..., None] if cfg.mrope
               else pos[:, None])[:, :, 0, :]
-
-    def upd(cache, new):
-        return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-                c, n[None], i, axis=0))(cache, new, kv_len)
-
-    with jax.named_scope("kv_cache"):
-        cache_k = upd(cache_k, k)             # (B, Smax, Hkv, dh)
-        cache_v = upd(cache_v, v)
-    y = decode_attention_xla(
-        q, cache_k.transpose(0, 2, 1, 3), cache_v.transpose(0, 2, 1, 3),
-        kv_len + 1)
+    k, v = k.astype(cache_k.dtype), v.astype(cache_v.dtype)  # as stored
+    y = decode_attention_xla(q, cache_k, cache_v, kv_len, new=(k, v))
     y = y.reshape(b, h * dh)
-    return y @ p["wo"], cache_k, cache_v
+    return y @ p["wo"], k, v
 
 
 # ---------------------------------------------------------------------------
@@ -227,7 +219,10 @@ def _mla_seq(p, x, positions, cfg: ArchConfig, kv_len=None):
 
 def mla_decode(p, x, positions, cfg: ArchConfig, cache_ckv, cache_kr, kv_len):
     """Absorbed-matmul MLA decode: the latent cache is attended directly.
-    x: (B, D); cache_ckv: (B, Smax, kv_lora); cache_kr: (B, Smax, d_rope)."""
+    x: (B, D); cache_ckv: (B, Smax, kv_lora); cache_kr: (B, Smax, d_rope),
+    read only: as `attention_decode`, the token attends to the cached
+    [0, kv_len) and to itself, and its (ckv, kr) are returned for the caller
+    to write at kv_len."""
     b, d = x.shape
     m, h = cfg.mla, cfg.n_heads
     qn, qr = _mla_q(p, x, positions, cfg)                    # (B,H,dn),(B,H,dr)
@@ -235,30 +230,25 @@ def mla_decode(p, x, positions, cfg: ArchConfig, cache_ckv, cache_kr, kv_len):
     ckv = rms_norm(kv[..., :m.kv_lora], p["kv_ln"], cfg.norm_eps)  # (B,Lr)
     kr = apply_rope(kv[..., m.kv_lora:][:, None, None, :],
                     positions[:, None], cfg.rope_theta)[:, 0, 0, :]
-
-    def upd(cache, new):
-        return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-                c, n[None], i, axis=0))(cache, new, kv_len)
-
-    with jax.named_scope("kv_cache"):
-        cache_ckv = upd(cache_ckv, ckv)
-        cache_kr = upd(cache_kr, kr)
+    ckv, kr = ckv.astype(cache_ckv.dtype), kr.astype(cache_kr.dtype)
 
     wk_b = p["wk_b"].reshape(m.kv_lora, h, m.d_nope)
     q_lat = jnp.einsum("bhn,lhn->bhl", qn, wk_b)             # absorb W_uk
     scale = 1.0 / jnp.sqrt(jnp.float32(m.d_nope + m.d_rope))
     logits = (jnp.einsum("bhl,bsl->bhs", q_lat, cache_ckv)
               + jnp.einsum("bhr,bsr->bhs", qr, cache_kr)) * scale
-    s_max = cache_ckv.shape[1]
-    valid = jax.lax.broadcasted_iota(jnp.int32, (b, s_max), 1) < (kv_len + 1)[:, None]
-    logits = jnp.where(valid[:, None, :], logits.astype(jnp.float32), -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(cache_ckv.dtype)
-    latent = jnp.einsum("bhs,bsl->bhl", probs, cache_ckv)
+    logit_new = (jnp.einsum("bhl,bl->bh", q_lat, ckv)
+                 + jnp.einsum("bhr,br->bh", qr, kr)) * scale
+    probs, p_new = decode_softmax(logits, kv_len, logit_new)
+    dtype = cache_ckv.dtype
+    latent = (jnp.einsum("bhs,bsl->bhl", probs.astype(dtype), cache_ckv,
+                         preferred_element_type=jnp.float32)
+              + p_new.astype(dtype).astype(jnp.float32)[..., None]
+              * ckv[:, None, :].astype(jnp.float32)).astype(dtype)
     wv_b = p["wv_b"].reshape(m.kv_lora, h, m.v_head_dim)
     y = jnp.einsum("bhl,lhv->bhv", latent, wv_b)             # absorb W_uv
     y = y.reshape(b, h * m.v_head_dim)
-    return y @ p["wo"], cache_ckv, cache_kr
+    return y @ p["wo"], ckv, kr
 
 
 # ---------------------------------------------------------------------------
@@ -293,14 +283,13 @@ def _layer_seq(lp, x, positions, cfg: ArchConfig, kv_len=None):
 
 
 def _layer_decode(lp, x, positions, cfg: ArchConfig, cache, kv_len):
+    """One layer for one token; ``cache`` is the layer's two cache leaves,
+    read only.  Returns (x, the token's own entries for those leaves)."""
     with jax.named_scope("attention"):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        if cfg.family == "mla":
-            y, c0, c1 = mla_decode(lp["attn"], h, positions, cfg,
-                                   cache[0], cache[1], kv_len)
-        else:
-            y, c0, c1 = attention_decode(lp["attn"], h, positions, cfg,
-                                         cache[0], cache[1], kv_len)
+        decode = mla_decode if cfg.family == "mla" else attention_decode
+        y, c0, c1 = decode(lp["attn"], h, positions, cfg, cache[0], cache[1],
+                           kv_len)
     x = x + y
     with jax.named_scope("ffn"):
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -439,7 +428,11 @@ def prefill(params, cfg: ArchConfig, batch, max_seq: int):
 
 def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
     """One decode step.  tokens: (B,) int32 (or embeds (B, D)).
-    Returns (logits (B, V), new cache)."""
+    Returns (logits (B, V), new cache).
+
+    Each layer reads its cache slab where it lies; the new token's K/V are
+    written at (layer, slot, kv_len) after the layer scan, so a donated cache
+    is updated in place and never copied whole."""
     if cfg.inputs == "embeddings" and tokens.ndim == 2:
         x = tokens
     else:
@@ -451,45 +444,35 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
         positions = kv_len
         if cfg.mrope:  # text continuation: t advances, h/w stay 0
             positions = jnp.stack([kv_len, kv_len * 0, kv_len * 0], 0)
+    names = ("ckv", "kr") if cfg.family == "mla" else ("k", "v")
+    c0, c1 = cache[names[0]], cache[names[1]]
 
-    xs_dense = None
-    if cfg.family == "mla":
-        nd = (cache["ckv"].shape[0] - params["layers"]["ln1"].shape[0]
-              if "dense_layers" in params else 0)
-        if nd:
-            xs_dense = (params["dense_layers"], cache["ckv"][:nd],
-                        cache["kr"][:nd])
-        xs = (params["layers"], cache["ckv"][nd:], cache["kr"][nd:])
-    else:
-        xs = (params["layers"], cache["k"], cache["v"])
-
-    def scan_fn(x, lp_and_cache):
-        lp, c0, c1 = lp_and_cache
+    def scan_fn(x, lp_and_layer):
+        lp, layer = lp_and_layer
         x = _dist.shard_activation(x)
-        x, (n0, n1) = _layer_decode(lp, x, positions, cfg, (c0, c1), kv_len)
-        return x, (n0, n1)
+        rows = (jax.lax.dynamic_index_in_dim(c0, layer, keepdims=False),
+                jax.lax.dynamic_index_in_dim(c1, layer, keepdims=False))
+        return _layer_decode(lp, x, positions, cfg, rows, kv_len)
 
-    new_caches = []
+    stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
     for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        new_caches = []
-        if xs_dense is not None:
-            x, nc = jax.lax.scan(scan_fn, x, xs_dense)
-            new_caches.append(nc)
-        x, nc = jax.lax.scan(scan_fn, x, xs)
-        new_caches.append(nc)
+        new, first = [], 0
+        for stack in stacks:
+            n = jax.tree.leaves(stack)[0].shape[0]
+            x, nc = jax.lax.scan(scan_fn, x,
+                                 (stack, jnp.arange(first, first + n)))
+            new.append(nc)
+            first += n
+    n0, n1 = (jnp.concatenate(a, 0) for a in zip(*new))   # (L, B, ...)
 
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed", params["embed"])
     with jax.named_scope("head"):
         logits = jnp.einsum("bd,vd->bv", x, unembed,
                             preferred_element_type=jnp.float32)
-    if len(new_caches) == 2:
-        n0 = jax.tree.map(lambda a, c: jnp.concatenate([a, c], 0),
-                          new_caches[0], new_caches[1])
-    else:
-        n0 = new_caches[0]
-    if cfg.family == "mla":
-        new_cache = {"ckv": n0[0], "kr": n0[1], "len": kv_len + 1}
-    else:
-        new_cache = {"k": n0[0], "v": n0[1], "len": kv_len + 1}
+    with jax.named_scope("kv_cache"):
+        slots = jnp.arange(b)
+        new_cache = {names[0]: c0.at[:, slots, kv_len].set(n0),
+                     names[1]: c1.at[:, slots, kv_len].set(n1),
+                     "len": kv_len + 1}
     return logits, new_cache

@@ -8,7 +8,8 @@ This is the serving analogue of the paper's evaluation loop — sequential
 admission, batched execution.
 
 The device programs are named ``jit_prefill``, ``jit_write_slot`` and
-``jit_decode_step``.  Each step of the host loop runs in a ``repro.*`` span
+``jit_decode_step``; the last two take the cache donated and update it in
+place (`programs`).  Each step of the host loop runs in a ``repro.*`` span
 (``repro.obs``): ``admit`` (``rid``, ``slot``, ``tokens``) around one
 request's admission, holding ``prefill`` (``rid``, ``tokens``,
 ``attn_steps``: the flash-loop steps per attention layer),
@@ -63,10 +64,25 @@ def _batch_axis(key: str) -> int:
 
 def write_slot(cache, cache1, slot):
     """``cache`` with slot ``slot`` (traced) replaced by the one-slot
-    ``cache1``: a new copy of each array, as ``.at[].set`` makes."""
+    ``cache1``; in place where ``cache`` is donated."""
     return {k: jax.lax.dynamic_update_slice_in_dim(
                 v, cache1[k].astype(v.dtype), slot, _batch_axis(k))
             for k, v in cache.items()}
+
+
+def programs(cfg: ArchConfig, max_seq: int):
+    """The engine's device programs, jitted from named functions:
+    ``jit_prefill``; ``jit_decode_step`` and ``jit_write_slot``, which donate
+    the cache they are given, so that the engine's one cache is updated in
+    place and never copied whole."""
+    def prefill(p, b):
+        return api.prefill(p, cfg, b, max_seq)
+
+    def decode_step(p, c, t):
+        return api.decode_step(p, cfg, c, t)
+
+    return (jax.jit(prefill), jax.jit(decode_step, donate_argnums=1),
+            jax.jit(write_slot, donate_argnums=0))
 
 
 class Engine:
@@ -97,16 +113,8 @@ class Engine:
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
 
-        # named functions: the programs are jit_prefill, jit_decode_step
-        def prefill(p, b):
-            return api.prefill(p, cfg, b, max_seq)
-
-        def decode_step(p, c, t):
-            return api.decode_step(p, cfg, c, t)
-
-        self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode_step)
-        self._write_slot = jax.jit(write_slot)
+        self._prefill, self._decode, self._write_slot = programs(cfg,
+                                                                 max_seq)
 
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> None:

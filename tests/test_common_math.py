@@ -1,11 +1,13 @@
-"""Numerics of the shared layers: RoPE/M-RoPE, RMSNorm, chunked xent."""
+"""Numerics of the shared layers: RoPE/M-RoPE, RMSNorm, chunked xent,
+decode attention."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.models.common import (
-    apply_mrope, apply_rope, chunked_softmax_xent, rms_norm,
+    apply_mrope, apply_rope, chunked_softmax_xent, decode_attention_xla,
+    rms_norm,
 )
 
 
@@ -81,3 +83,28 @@ def test_chunked_xent_grads_match(rng):
     g2 = jax.grad(f_direct, argnums=(0, 1))(x, emb)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def test_decode_attention_of_the_new_token_equals_writing_it_first(rng):
+    """bfloat16, slots at lengths 0, 5 and S - 1: attending to the cached
+    [0, kv_len) plus the token's own K/V gives what writing them at kv_len
+    and attending to kv_len + 1 positions gives."""
+    b, s, hkv, g, d = 3, 16, 2, 4, 32
+    bf16 = jnp.bfloat16
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), bf16) for shape in
+               [(b, hkv * g, d), (b, s, hkv, d), (b, s, hkv, d)])
+    k1, v1 = (jnp.asarray(rng.standard_normal((b, hkv, d)), bf16)
+              for _ in range(2))
+    kv_len = jnp.array([0, 5, s - 1], jnp.int32)
+    slots = jnp.arange(b)
+    want = decode_attention_xla(q, k.at[slots, kv_len].set(k1),
+                                v.at[slots, kv_len].set(v1), kv_len + 1)
+    got = decode_attention_xla(q, k, v, kv_len, new=(k1, v1))
+    assert got.dtype == want.dtype == bf16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2 ** -7, atol=2 ** -7)
+    # the slot at 0 sees only its own value
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32),
+        np.asarray(jnp.repeat(v1[0], g, axis=0), np.float32), rtol=2 ** -7)

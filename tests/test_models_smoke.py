@@ -74,6 +74,54 @@ def test_prefill_decode_matches_forward(arch):
         atol=2e-3, rtol=2e-3)
 
 
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "deepseek-v2-236b"])
+def test_decode_step_writes_only_the_new_entries(arch):
+    """Slots at lengths 0, 7 and max_seq - 1 over a cache whose unused
+    positions hold noise: each slot's logits are a prefill's over its
+    history and the new token, its new K/V land at (layer, slot, length),
+    and every other cache position keeps its value, bit for bit."""
+    cfg = dataclasses.replace(configs.get_reduced(arch),
+                              param_dtype="float32")
+    if cfg.moe is not None:  # drops in prefill-but-not-decode break parity
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=100.0))
+    params = api.init_params(cfg, jax.random.key(2))
+    max_seq, lens = 16, [0, 7, 15]
+    rng = np.random.default_rng(0)
+    hist = [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in lens]
+    new_tok = rng.integers(0, cfg.vocab, len(lens)).astype(np.int32)
+
+    prefill = jax.jit(lambda t: api.prefill(params, cfg, {"tokens": t[None]},
+                                            max_seq))
+    cache = {k: rng.standard_normal(v.shape).astype(np.float32)
+             for k, v in api.init_cache(cfg, len(lens), max_seq).items()
+             if k != "len"}
+    for slot, h in enumerate(hist):
+        if len(h):
+            _, one = prefill(h)
+            for k, c in cache.items():
+                c[:, slot, :len(h)] = np.asarray(one[k])[:, 0, :len(h)]
+    cache["len"] = np.asarray(lens, np.int32)
+    logits, out = jax.jit(lambda c, t: api.decode_step(params, cfg, c, t))(
+        cache, jnp.asarray(new_tok))
+
+    np.testing.assert_array_equal(np.asarray(out["len"]),
+                                  np.asarray(lens) + 1)
+    want = {k: c.copy() for k, c in cache.items() if k != "len"}
+    for slot, n in enumerate(lens):
+        full_logits, full = prefill(np.append(hist[slot], new_tok[slot]))
+        np.testing.assert_allclose(np.asarray(logits[slot]),
+                                   np.asarray(full_logits[0]),
+                                   atol=2e-3, rtol=2e-3)
+        for k, w in want.items():
+            got = np.asarray(out[k])[:, slot, n]
+            np.testing.assert_allclose(got, np.asarray(full[k])[:, 0, n],
+                                       atol=1e-5, rtol=1e-5)
+            w[:, slot, n] = got
+    for k, w in want.items():
+        np.testing.assert_array_equal(np.asarray(out[k]), w)
+
+
 def test_moe_capacity_dropless_at_decode():
     from repro.models.config import MoEConfig
     from repro.models.moe import _capacity

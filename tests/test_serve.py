@@ -139,7 +139,9 @@ def test_jitted_slot_write_equals_the_eager_one():
                                            jnp.int32)})
     compiled = []
     for slot in range(3):
-        got = eng._write_slot(cache, cache1, np.int32(slot))
+        # the destination is donated: each call writes into its own copy
+        got = eng._write_slot(jax.tree.map(jnp.copy, cache), cache1,
+                              np.int32(slot))
         compiled.append(eng._write_slot._cache_size())
         for k, dst in cache.items():
             ax = 0 if k == "len" else 1
@@ -150,6 +152,30 @@ def test_jitted_slot_write_equals_the_eager_one():
             np.testing.assert_array_equal(np.asarray(got[k]),
                                           np.asarray(want))
     assert compiled[0] == compiled[-1]          # the slot is traced
+
+
+def test_engine_donates_its_cache_and_generates_as_without_donation():
+    """`jit_decode_step` and `jit_write_slot` take the engine's cache over:
+    after a tick the cache it held before is deleted, and the tokens are
+    those of the same programs jitted without donation."""
+    prompts = [np.arange(a, b, dtype=np.int32)
+               for a, b in [(1, 6), (3, 12), (2, 9)]]
+    outputs = []
+    for donate in (True, False):
+        eng = _tiny_engine()
+        if not donate:
+            eng._decode = jax.jit(eng._decode.__wrapped__)
+            eng._write_slot = jax.jit(eng._write_slot.__wrapped__)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new=5))
+        before = eng.cache
+        eng.tick()                  # admits two: two slot writes, one step
+        assert all(x.is_deleted() == donate
+                   for x in jax.tree.leaves(before))
+        outputs.append([r.generated for r in
+                        sorted(eng.run(), key=lambda r: r.rid)])
+    assert outputs[0] == outputs[1]
+    assert [len(g) for g in outputs[0]] == [5, 5, 5]
 
 
 def test_requests_are_stamped_as_their_admission_begins():

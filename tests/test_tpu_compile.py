@@ -12,6 +12,7 @@ this file.  Keep these compiles in this one file.
 """
 import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from repro import configs
 from repro.core import codegen
 from repro.core.genome import SEED_MXU, KernelGenome
 from repro.models import api
+from repro.serve.engine import programs
 
 #: v5e HBM as XLA reports it (16 GB nominal).
 V5E_HBM_BYTES = 15.75 * 2**30
@@ -136,3 +138,39 @@ def test_qwen_full_width_serving_fits_one_v5e(one_chip, kind):
         compiled = _compile(lambda p, c, t: api.decode_step(p, cfg, c, t),
                             params, cache, tokens)
     assert 0 < _bytes(compiled) < V5E_HBM_BYTES, compiled.memory_analysis()
+
+
+def _aliased_params(compiled) -> set:
+    """Entry parameters whose buffers the compiled program's outputs reuse
+    (the module header's ``input_output_alias``)."""
+    header = compiled.as_text().split("\n", 1)[0]
+    return {int(p) for p in re.findall(r"\{[\d,\s]*\}: \((\d+),", header)}
+
+
+def test_engine_updates_the_serving_cache_in_place(one_chip):
+    """`jit_decode_step` and `jit_write_slot` as `Engine` jits them, for
+    qwen2.5-3b at the serving cell's 20 slots x 4096: every cache leaf is
+    aliased to the output, and the decode step's temporaries stay under 5%
+    of one cache copy, so no copy of the cache is made beside it."""
+    cfg = configs.get_config("qwen2.5-3b")
+    slots, max_seq = 20, 4096
+    on_chip = functools.partial(jax.tree.map,
+                                lambda x: _sds(one_chip, x.shape, x.dtype))
+    params = on_chip(api.abstract_params(cfg))
+    cache, cache1 = (on_chip(jax.eval_shape(functools.partial(
+        api.init_cache, cfg, n, max_seq, dtype=jnp.dtype(cfg.param_dtype))))
+        for n in (slots, 1))
+    _, decode, write_slot = programs(cfg, max_seq)
+    dec = decode.lower(params, cache, _sds(one_chip, (slots,), jnp.int32)
+                       ).compile()
+    ws = write_slot.lower(cache, cache1, _sds(one_chip, (), jnp.int32)
+                          ).compile()
+
+    n_p, n_c = len(jax.tree.leaves(params)), len(jax.tree.leaves(cache))
+    assert _aliased_params(dec) == set(range(n_p, n_p + n_c))
+    assert _aliased_params(ws) == set(range(n_c))
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert cache_bytes > 3e9                    # 3.02 GB: 20 x 4096 x 36,864 B
+    temp = dec.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * cache_bytes, temp
