@@ -8,7 +8,6 @@ Prints ``name,value,derived`` CSV:
   scientist/*   — campaign throughput: submissions/hour + cache hit rate
                   for workers ∈ {1, 3} (also writes BENCH_scientist.json)
   micro/*       — kernel microbenchmarks (interpret wall-clock + v5e est.)
-  roofline/*    — §Roofline terms per dry-run cell (needs results/dryrun)
 """
 from __future__ import annotations
 
@@ -24,8 +23,8 @@ def main(argv=None) -> int:
     gens = 6 if args.fast else 20
 
     rows = []
-    from benchmarks import (kernel_micro, roofline_bench, scientist_throughput,
-                            table1, trajectory)
+    from benchmarks import (kernel_micro, scientist_throughput, table1,
+                            trajectory)
     t1, _ = table1.run(generations=gens)
     rows += t1
     tr, _ = trajectory.run(generations=max(4, gens // 2))
@@ -33,7 +32,6 @@ def main(argv=None) -> int:
     st, _ = scientist_throughput.run(generations=max(4, gens // 3))
     rows += st
     rows += kernel_micro.run()
-    rows += roofline_bench.run()
 
     print("name,value,derived")
     for name, value, derived in rows:

@@ -23,7 +23,7 @@ citizen" rule of §3.4 — a busy service raises ``ServiceBusyError``); the
 pool is what scales, by routing queued submissions to whichever worker is
 free.  Three priority tiers: ``PRIORITY_URGENT`` (jump the queue — e.g. a
 re-evaluation the drain is blocked on) < ``PRIORITY_CAMPAIGN`` (generation
-submissions) < ``PRIORITY_PROBE`` (idle-time autotune/benchmark probes).
+submissions) < ``PRIORITY_PROBE`` (idle-time benchmark probes).
 ``pause()`` stops workers from starting *new* jobs (in-flight evaluations
 finish; the queue keeps accepting); ``resume()`` continues.
 
@@ -109,7 +109,7 @@ from .transport import WorkerDiedError, WorkerTransport, make_transport
 #: Queue priorities (lower value = served first).
 PRIORITY_URGENT = -10            # jump the queue: drain-blocking work
 PRIORITY_CAMPAIGN = 0            # generation submissions
-PRIORITY_PROBE = 10              # idle-time autotune/benchmark probes
+PRIORITY_PROBE = 10              # idle-time benchmark probes
 _PRIORITY_SHUTDOWN = 10 ** 9     # sentinels drain after all real work
 
 
@@ -409,7 +409,7 @@ class EvalPool:
         return self.submit_async(source, **kwargs).result()
 
     def probe(self, source: str, tag=None) -> EvalHandle:
-        """Low-priority idle-time work (autotune/benchmark probes): only
+        """Low-priority idle-time work (benchmark probes): only
         reaches a worker when no campaign submission is queued."""
         return self.submit_async(source, priority=PRIORITY_PROBE, tag=tag)
 

@@ -6,8 +6,8 @@ Supports causal masking, GQA (grouped KV heads) and local (sliding-window)
 attention — the latter is what makes ``recurrentgemma``'s 2048-window layers
 linear in sequence length.
 
-Block sizes are exposed as parameters so the Kernel Scientist can tune them
-(see repro.core.autotune).
+Block sizes are exposed as parameters so the Kernel Scientist can tune
+them.
 """
 from __future__ import annotations
 

@@ -1,18 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
-the production meshes and extract the roofline terms.
+the production meshes, on 512 host (CPU) devices, as a compile proof.
 
 For each cell this prints/records:
   * compiled.memory_analysis()  — proves the cell fits per-device HBM
-  * compiled.cost_analysis()    — HLO FLOPs / bytes for §Roofline
+  * compiled.cost_analysis()    — the compiler's FLOPs / bytes (a while-loop
+    body is counted once)
   * collective bytes parsed from the optimized HLO (all-gather, all-reduce,
     reduce-scatter, all-to-all, collective-permute operand sizes)
-
-Scan correction: XLA's cost_analysis counts a while-loop body ONCE, so all
-scanned-layer models undercount by ~L; we additionally lower a single-layer
-body with identical shardings and report corrected = full + (L-1) * body.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k
@@ -20,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import pathlib
 import sys
 import time
@@ -34,7 +29,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import SHAPES, api
 from repro.models.config import ArchConfig, ShapeConfig
 from repro.optim import adamw
-from repro.roofline import report
 from repro.roofline.collectives import collective_bytes_from_hlo
 from repro.train import step as train_step_mod
 
@@ -135,53 +129,24 @@ def build_cell(cfg: ArchConfig, shape: ShapeConfig, mesh):
 
 
 # ---------------------------------------------------------------------------
-def _lower_costs(cfg: ArchConfig, shape: ShapeConfig, mesh,
-                 want_memory: bool = False) -> dict:
+def _lower_costs(cfg: ArchConfig, shape: ShapeConfig, mesh) -> dict:
     fn, args, shardings, out_shardings, donate = build_cell(cfg, shape, mesh)
     jfn = jax.jit(fn, in_shardings=shardings, out_shardings=out_shardings,
                   donate_argnums=donate)
     compiled = jfn.lower(*args).compile()
     cost = compiled.cost_analysis()
-    out = {
+    return {
         "flops": cost.get("flops", 0.0),
         "bytes_accessed": cost.get("bytes accessed", 0.0),
         "collective_bytes": collective_bytes_from_hlo(compiled.as_text()),
+        "memory": _mem_dict(compiled.memory_analysis()),
     }
-    if want_memory:
-        out["memory"] = _mem_dict(compiled.memory_analysis())
-    return out
-
-
-def body_repeats(cfg: ArchConfig) -> float:
-    """How many times the scanned layer body repeats in the real model.
-    deepseek-v2's single leading dense-FFN layer is flop-matched to an MoE
-    layer by construction (top6*1536 + 2*1536 == 12288 * 3/3), so the
-    two-stack delta is treated as two equal bodies."""
-    if cfg.family == "rglru":
-        pat = len(cfg.rglru.pattern)
-        return cfg.n_layers / pat   # super-blocks (+ tail as a fraction)
-    return float(cfg.n_layers)
-
-
-def _n_stacks(cfg: ArchConfig) -> int:
-    n = 1
-    if cfg.moe and cfg.moe.n_dense_layers:
-        n += 1
-    if cfg.family == "rglru" and cfg.n_layers % len(cfg.rglru.pattern):
-        n += 1
-    return n
 
 
 def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
-             verbose: bool = True, layer_correction: bool = True) -> dict:
-    """One dry-run cell.  Single-pod cells get three lowerings:
-      prod    — production config: memory_analysis + compile proof
-      exact1  — exact_count=True: inner scans unrolled, body counted once
-      exact2  — exact_count + scan_repeats=2: delta isolates one layer body
-    corrected = exact1 + (body_repeats - 1) * (exact2 - exact1) / n_stacks
-    (assembled in repro.roofline.report).  Multi-pod cells compile-prove
-    only (the roofline table is single-pod per the spec)."""
-    import dataclasses
+             verbose: bool = True) -> dict:
+    """One dry-run cell: the production config lowered and compiled, with
+    its memory analysis, the compiler's costs and its collective bytes."""
     cfg = configs.get_config(arch_id)
     shape = SHAPES[shape_name]
     ok, why = configs.applicable(cfg, shape)
@@ -199,20 +164,8 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
                 "arch": arch_id, "shape": shape_name,
                 "mesh": "multi" if multi_pod else "single",
                 "status": "ok", "n_devices": mesh.size,
-                "prod": _lower_costs(cfg, shape, mesh, want_memory=True),
+                "prod": _lower_costs(cfg, shape, mesh),
             }
-            if layer_correction and not multi_pod:
-                # coarser tiles in accounting mode: same FLOP coverage
-                # (within diagonal-block rounding), ~10x fewer unrolled
-                # bodies => tractable compile times
-                acct = dict(exact_count=True, attn_q_chunk=2048,
-                            attn_k_chunk=2048, loss_chunk=32768)
-                cfg1 = dataclasses.replace(cfg, **acct)
-                cfg2 = dataclasses.replace(cfg, scan_repeats=2, **acct)
-                result["exact1"] = _lower_costs(cfg1, shape, mesh)
-                result["exact2"] = _lower_costs(cfg2, shape, mesh)
-                result["body_repeats"] = body_repeats(cfg)
-                result["n_stacks"] = _n_stacks(cfg)
             result["compile_s"] = round(time.time() - t0, 1)
     except Exception as e:
         result = {
@@ -267,8 +220,11 @@ def main(argv=None) -> int:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default=None,
                     help="directory for per-cell JSON records")
-    ap.add_argument("--no-layer-correction", action="store_true")
     args = ap.parse_args(argv)
+    if not args.all and not (args.arch and args.shape):
+        ap.error("give --arch and --shape, or --all")
+    # read when the backend starts, which nothing before this line does
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     cells = []
     if args.all:
@@ -276,7 +232,6 @@ def main(argv=None) -> int:
             for sname in SHAPES:
                 cells.append((aid, sname))
     else:
-        assert args.arch and args.shape, "--arch/--shape or --all"
         cells.append((args.arch, args.shape))
     meshes = {"single": (False,), "multi": (True,),
               "both": (False, True)}[args.mesh]
@@ -291,8 +246,7 @@ def main(argv=None) -> int:
             if outdir and (outdir / f"{key}.json").exists():
                 print(f"CACHED {key}")
                 continue
-            r = run_cell(aid, sname, mp,
-                         layer_correction=not args.no_layer_correction)
+            r = run_cell(aid, sname, mp)
             if r["status"] == "error":
                 failures += 1
             if outdir:

@@ -132,8 +132,7 @@ def _block_logits_masked(s, qs, ks, q_chunk, k_chunk, scale, causal, window,
 
 @functools.lru_cache(maxsize=None)
 def _make_blockwise(b, h, sq, sk, d, dv, q_chunk, k_chunk, scale,
-                    causal, window, q_offset, has_kv_len, dtype_name,
-                    unroll=False):
+                    causal, window, q_offset, has_kv_len, dtype_name):
     """FLOP-exact flash attention over the statically-visible chunk pairs,
     with a hand-written (flash) VJP: the forward saves only (q, k, v, out,
     m, l) — O(S) residuals — and the backward recomputes each score block,
@@ -188,8 +187,7 @@ def _make_blockwise(b, h, sq, sk, d, dv, q_chunk, k_chunk, scale,
             if plist:
                 carry, _ = jax.lax.scan(
                     functools.partial(step, apply_mask=msk), carry,
-                    np.asarray(plist, np.int32),
-                    unroll=len(plist) if unroll else 1)
+                    np.asarray(plist, np.int32))
         acc, m, l = carry
         denom = jnp.where(l == 0.0, 1.0, l)
         out = (acc / denom[..., None]).astype(q.dtype)
@@ -247,8 +245,7 @@ def _make_blockwise(b, h, sq, sk, d, dv, q_chunk, k_chunk, scale,
             if plist:
                 carry, _ = jax.lax.scan(
                     functools.partial(step, apply_mask=msk), carry,
-                    np.asarray(plist, np.int32),
-                    unroll=len(plist) if unroll else 1)
+                    np.asarray(plist, np.int32))
         dq, dk, dv_ = carry
         return (dq.astype(q.dtype), dk.astype(k.dtype),
                 dv_.astype(v.dtype), None)
@@ -303,7 +300,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None,
                         q_chunk: int = 512, k_chunk: int = 1024,
                         scale: Optional[float] = None,
-                        kv_len=None, q_offset: int = 0, unroll: bool = False):
+                        kv_len=None, q_offset: int = 0):
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D), Hq % Hkv == 0.
 
     kv_len: optional (B,) valid KV prefix lengths (cached decode/prefill).
@@ -342,7 +339,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     attn = _make_blockwise(b, hq, plan.sq, plan.sk, d, dv, q_chunk, k_chunk,
                            float(scale), causal, window, q_offset,
-                           kv_len is not None, str(q.dtype), unroll)
+                           kv_len is not None, str(q.dtype))
     out = attn(q, k, v, kv_len)
     if plan.sq > sq:
         out = out[:, :, :sq]
@@ -398,8 +395,7 @@ def decode_attention_xla(q, k, v, kv_len, *, new=None, scale=None):
 # ---------------------------------------------------------------------------
 # Chunked cross-entropy: never materialise the full (T, V) logits.
 # ---------------------------------------------------------------------------
-def chunked_softmax_xent(x, emb_out, labels, weights=None, chunk: int = 8192,
-                         unroll: bool = False):
+def chunked_softmax_xent(x, emb_out, labels, weights=None, chunk: int = 8192):
     """x: (T, D); emb_out: (V, D); labels: (T,) int32; weights: (T,) or None.
     Returns (sum_nll, sum_weight)."""
     t, d = x.shape
@@ -425,7 +421,7 @@ def chunked_softmax_xent(x, emb_out, labels, weights=None, chunk: int = 8192,
 
     (nll_sum, w_sum), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xc, lc, wc), unroll=n_chunks if unroll else 1)
+        (xc, lc, wc))
     return nll_sum, w_sum
 
 

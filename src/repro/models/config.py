@@ -71,13 +71,6 @@ class ArchConfig:
     loss_chunk: int = 8192           # vocab-softmax token chunking
     attn_q_chunk: int = 512          # blockwise-attention tile sizes (XLA path)
     attn_k_chunk: int = 1024
-    # --- dry-run accounting knobs (see launch/dryrun.py) -------------------
-    # XLA cost_analysis counts a while-loop body once; exact_count unrolls
-    # the *inner* scans (attention pairs, SSD chunks, loss chunks) so they
-    # are counted fully, and scan_repeats=2 runs each layer stack twice so
-    # the cost delta isolates exactly one layer body.
-    exact_count: bool = False
-    scan_repeats: int = 1
 
     @property
     def head_dim_(self) -> int:

@@ -166,8 +166,7 @@ def _attn_seq(bp, x, positions, cfg: ArchConfig):
     k = apply_rope(k, positions, cfg.rope_theta)
     y = blockwise_attention(q, k, v, causal=True, window=cfg.rglru.window,
                             q_chunk=cfg.attn_q_chunk,
-                            k_chunk=cfg.attn_k_chunk,
-                            unroll=cfg.exact_count)
+                            k_chunk=cfg.attn_k_chunk)
     y = y.transpose(0, 2, 1, 3).reshape(b, s, hh * dh)
     return jnp.einsum("bsk,kd->bsd", y, p["wo"]), (k, v)
 
@@ -213,14 +212,13 @@ def forward(params, cfg: ArchConfig, batch, collect_cache: bool = False):
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     pat = cfg.rglru.pattern
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        x, caches = _scan_stack(params["super"], x, positions, cfg, pat,
-                                cfg.remat)
-        tail_caches = None
-        if "tail" in params:
-            n_tail = cfg.n_layers % len(pat)
-            x, tail_caches = _scan_stack(params["tail"], x, positions, cfg,
-                                         pat[:n_tail], cfg.remat)
+    x, caches = _scan_stack(params["super"], x, positions, cfg, pat,
+                            cfg.remat)
+    tail_caches = None
+    if "tail" in params:
+        n_tail = cfg.n_layers % len(pat)
+        x, tail_caches = _scan_stack(params["tail"], x, positions, cfg,
+                                     pat[:n_tail], cfg.remat)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if collect_cache:
         return x, (caches, tail_caches)
@@ -233,7 +231,7 @@ def loss_fn(params, cfg: ArchConfig, batch):
     unembed = params.get("unembed", params["embed"])
     nll, denom = chunked_softmax_xent(
         hidden.reshape(b * s, d), unembed, batch["labels"].reshape(b * s),
-        None, chunk=cfg.loss_chunk, unroll=cfg.exact_count)
+        None, chunk=cfg.loss_chunk)
     loss = nll / jnp.maximum(denom, 1.0)
     return loss, {"nll": loss}
 
@@ -396,8 +394,7 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
         return x, (jnp.stack(new_k), jnp.stack(new_v),
                    jnp.stack(new_s), jnp.stack(new_c))
 
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        x, (nk, nv, ns, nc) = jax.lax.scan(body, x, xs)
+    x, (nk, nv, ns, nc) = jax.lax.scan(body, x, xs)
     nk = nk.reshape((-1,) + nk.shape[2:])
     nv = nv.reshape((-1,) + nv.shape[2:])
     ns = ns.reshape((-1,) + ns.shape[2:])

@@ -63,8 +63,7 @@ def init_params(cfg: ArchConfig, key):
 # ---------------------------------------------------------------------------
 # Chunked SSD (jnp)
 # ---------------------------------------------------------------------------
-def ssd_chunked(x, dt, a, b, c, chunk: int, state0=None,
-                unroll: bool = False):
+def ssd_chunked(x, dt, a, b, c, chunk: int, state0=None):
     """x: (B,S,H,P); dt: (B,S,H) (already softplus'd); a: (H,) negative;
     b, c: (B,S,N).  Returns (y (B,S,H,P), final_state (B,H,N,P) f32)."""
     import math
@@ -111,8 +110,7 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, state0=None,
               else state0)
     xs_t = (jnp.moveaxis(xs, 1, 0), jnp.moveaxis(las, 1, 0),
             jnp.moveaxis(bs, 1, 0), jnp.moveaxis(cs, 1, 0))
-    final, ys = jax.lax.scan(step, state0, xs_t,
-                             unroll=nc if unroll else 1)
+    final, ys = jax.lax.scan(step, state0, xs_t)
     y = jnp.moveaxis(ys, 0, 1).reshape(bsz, s, h, p)
     return y.astype(x.dtype), final
 
@@ -142,8 +140,7 @@ def _layer_seq(lp, x, cfg: ArchConfig):
     xs = xs.reshape(bsz, s, h, s_cfg.head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["dt_bias"])
     a = -jnp.exp(lp["a_log"])
-    y, final_state = ssd_chunked(xs, dt, a, b, c, s_cfg.chunk,
-                                 unroll=cfg.exact_count)
+    y, final_state = ssd_chunked(xs, dt, a, b, c, s_cfg.chunk)
     y = y + (xs.astype(jnp.float32) * lp["d_skip"][None, None, :, None]
              ).astype(y.dtype)
     y = y.reshape(bsz, s, d_in)
@@ -161,8 +158,7 @@ def forward(params, cfg: ArchConfig, batch, collect_cache: bool = False):
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        x, caches = jax.lax.scan(body, x, params["layers"])
+    x, caches = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if collect_cache:
         return x, caches
@@ -175,7 +171,7 @@ def loss_fn(params, cfg: ArchConfig, batch):
     unembed = params.get("unembed", params["embed"])
     nll, denom = chunked_softmax_xent(
         hidden.reshape(b * s, d), unembed, batch["labels"].reshape(b * s),
-        None, chunk=cfg.loss_chunk, unroll=cfg.exact_count)
+        None, chunk=cfg.loss_chunk)
     loss = nll / jnp.maximum(denom, 1.0)
     return loss, {"nll": loss}
 
@@ -244,9 +240,8 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
         x, st, cb = _layer_step(lp, _dist.shard_activation(x), st, cb, cfg)
         return x, (st, cb)
 
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        x, (states, convs) = jax.lax.scan(
-            body, x, (params["layers"], cache["state"], cache["conv"]))
+    x, (states, convs) = jax.lax.scan(
+        body, x, (params["layers"], cache["state"], cache["conv"]))
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     unembed = params.get("unembed", params["embed"])
     logits = jnp.einsum("bd,vd->bv", x, unembed,

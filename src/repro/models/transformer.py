@@ -137,8 +137,7 @@ def attention_seq(p, x, positions, cfg: ArchConfig, *, kv_len=None):
     k = _rope(cfg, k, positions)
     y = blockwise_attention(
         q, k, v, causal=not cfg.encoder_only, kv_len=kv_len,
-        q_chunk=cfg.attn_q_chunk, k_chunk=cfg.attn_k_chunk,
-        unroll=cfg.exact_count)
+        q_chunk=cfg.attn_q_chunk, k_chunk=cfg.attn_k_chunk)
     y = y.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
     return jnp.einsum("bsk,kd->bsd", y, p["wo"]), (k, v)
 
@@ -210,8 +209,7 @@ def _mla_seq(p, x, positions, cfg: ArchConfig, kv_len=None):
                         axis=-1)
     y = blockwise_attention(q, k, v, causal=True, kv_len=kv_len,
                             q_chunk=cfg.attn_q_chunk,
-                            k_chunk=cfg.attn_k_chunk,
-                            unroll=cfg.exact_count)
+                            k_chunk=cfg.attn_k_chunk)
     y = y.transpose(0, 2, 1, 3).reshape(b, s, h * m.v_head_dim)
     # cache = the rope'd shared key + normalised latent, per token
     return jnp.einsum("bsk,kd->bsd", y, p["wo"]), (ckv, kr[:, 0])
@@ -343,13 +341,11 @@ def forward(params, cfg: ArchConfig, batch):
 
     carry = (x, aux0)
     kvs = []
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        kvs = []
-        if "dense_layers" in params:
-            carry, kv_d = jax.lax.scan(scan_fn, carry, params["dense_layers"])
-            kvs.append(kv_d)
-        carry, kv_m = jax.lax.scan(scan_fn, carry, params["layers"])
-        kvs.append(kv_m)
+    if "dense_layers" in params:
+        carry, kv_d = jax.lax.scan(scan_fn, carry, params["dense_layers"])
+        kvs.append(kv_d)
+    carry, kv_m = jax.lax.scan(scan_fn, carry, params["layers"])
+    kvs.append(kv_m)
     x, aux = carry
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if len(kvs) == 1:
@@ -371,7 +367,7 @@ def loss_fn(params, cfg: ArchConfig, batch):
     with jax.named_scope("loss"):           # holds the head's "head" scope
         nll, denom = chunked_softmax_xent(
             hidden.reshape(b * s, d), unembed, labels, weights,
-            chunk=cfg.loss_chunk, unroll=cfg.exact_count)
+            chunk=cfg.loss_chunk)
     loss = nll / jnp.maximum(denom, 1.0)
     total = loss + 1e-2 * aux["moe_aux"] + 1e-3 * aux["moe_z"]
     return total, {"nll": loss, "moe_aux": aux["moe_aux"],
@@ -455,14 +451,12 @@ def decode_step(params, cfg: ArchConfig, cache, tokens, positions=None):
         return _layer_decode(lp, x, positions, cfg, rows, kv_len)
 
     stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
-    for _ in range(cfg.scan_repeats):   # >1 only in dry-run accounting mode
-        new, first = [], 0
-        for stack in stacks:
-            n = jax.tree.leaves(stack)[0].shape[0]
-            x, nc = jax.lax.scan(scan_fn, x,
-                                 (stack, jnp.arange(first, first + n)))
-            new.append(nc)
-            first += n
+    new, first = [], 0
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        x, nc = jax.lax.scan(scan_fn, x, (stack, jnp.arange(first, first + n)))
+        new.append(nc)
+        first += n
     n0, n1 = (jnp.concatenate(a, 0) for a in zip(*new))   # (L, B, ...)
 
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)

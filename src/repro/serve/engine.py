@@ -87,13 +87,12 @@ def programs(cfg: ArchConfig, max_seq: int):
 
 class Engine:
     def __init__(self, cfg: ArchConfig, params, *, slots: int = 4,
-                 max_seq: int = 512, prefill_pad: int = 1, mesh=None):
+                 max_seq: int = 512, mesh=None):
         assert not cfg.encoder_only, "encoder-only models cannot serve"
         self.cfg = cfg
         self.params = params
         self.slots = slots
         self.max_seq = max_seq
-        self.prefill_pad = prefill_pad
         self.mesh = mesh
         self.cache = api.init_cache(cfg, slots, max_seq,
                                     dtype=jnp.dtype(cfg.param_dtype))
@@ -128,23 +127,23 @@ class Engine:
             req.t_admit = time.perf_counter()
             with span("admit", rid=req.rid, slot=slot,
                       tokens=len(req.prompt)):
-                pad = -len(req.prompt) % self.prefill_pad
-                toks = np.pad(req.prompt, (0, pad))
-                with span("prefill", rid=req.rid, tokens=len(toks),
-                          attn_steps=api.attn_steps(self.cfg, len(toks))):
-                    batch = {"tokens": jnp.asarray(toks, jnp.int32)[None]}
+                n = len(req.prompt)
+                with span("prefill", rid=req.rid, tokens=n,
+                          attn_steps=api.attn_steps(self.cfg, n)):
+                    batch = {"tokens":
+                             jnp.asarray(req.prompt, jnp.int32)[None]}
                     if self.cfg.mrope:
-                        pos = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]
+                        pos = jnp.arange(n, dtype=jnp.int32)[None]
                         batch["positions"] = jnp.stack(
                             [pos, pos * 0, pos * 0], 0)
-                    # the prefill's cache holds its length, pad included
+                    # the prefill's cache holds its length
                     logits, cache1 = self._prefill(self.params, batch)
                 with span("write_slot", rid=req.rid, slot=slot):
                     self.cache = self._write_slot(self.cache, cache1,
                                                   np.int32(slot))
-                if pad == 0:   # last-position logits are the first new token
-                    with span("first_token", rid=req.rid):
-                        req.generated.append(int(jnp.argmax(logits[0])))
+                # last-position logits are the first new token
+                with span("first_token", rid=req.rid):
+                    req.generated.append(int(jnp.argmax(logits[0])))
                 self.active[slot] = req
 
     # --------------------------------------------------------------- tick

@@ -100,22 +100,3 @@ for _ in range(3):
 print("COMPRESSION_OK")
 """)
     assert "COMPRESSION_OK" in out
-
-
-@pytest.mark.slow
-def test_autotune_on_debug_mesh():
-    """Beyond-paper: the scientist's loop over framework genomes, evaluated
-    by compile-and-analyse on a small mesh."""
-    out = _run("""
-import jax
-from repro.core.autotune import FrameworkGenome, autotune_cell
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
-res = autotune_cell("qwen2.5-3b", "train_4k", budget=3, mesh=mesh,
-                    verbose=False)
-assert res["best"]["status"] == "ok", res["best"]
-assert res["submissions"] <= 3
-assert len(res["log"]) >= 1
-print("AUTOTUNE_OK", res["best"]["dominant"])
-""")
-    assert "AUTOTUNE_OK" in out

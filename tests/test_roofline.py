@@ -1,11 +1,8 @@
-"""Roofline assembly: collective parsing on synthetic HLO + correction math
-on synthetic dry-run records + model-flops accounting."""
-import pytest
-
+"""Collective parsing on synthetic HLO: the bytes and op counts the dry
+run records from a compiled program."""
 from repro.roofline.collectives import (
     collective_bytes_from_hlo, collective_op_counts,
 )
-from repro.roofline.report import cell_report, corrected_costs, model_flops
 
 HLO = """
 ENTRY main {
@@ -36,50 +33,3 @@ def test_collective_op_counts():
     assert counts["all-gather"] == 2
     assert counts["all-reduce"] == 1
     assert "add" not in counts
-
-
-def _rec(e1_flops=10.0, e2_flops=14.0, repeats=5, n_stacks=1):
-    return {
-        "arch": "qwen2.5-3b", "shape": "train_4k", "mesh": "single",
-        "status": "ok", "n_devices": 256,
-        "prod": {"flops": 1.0, "bytes_accessed": 1.0,
-                 "collective_bytes": 1.0,
-                 "memory": {"argument_size_in_bytes": 2 << 30,
-                            "temp_size_in_bytes": 6 << 30}},
-        "exact1": {"flops": e1_flops, "bytes_accessed": 8.0,
-                   "collective_bytes": 2.0},
-        "exact2": {"flops": e2_flops, "bytes_accessed": 10.0,
-                   "collective_bytes": 2.5},
-        "body_repeats": repeats, "n_stacks": n_stacks,
-    }
-
-
-def test_corrected_costs_formula():
-    c = corrected_costs(_rec())
-    assert c["flops"] == pytest.approx(10 + 4 * (14 - 10))   # e1 + (R-1)*body
-    assert c["bytes_accessed"] == pytest.approx(8 + 4 * 2)
-    c2 = corrected_costs(_rec(n_stacks=2))
-    assert c2["flops"] == pytest.approx(10 + 4 * 4 / 2)
-
-
-def test_cell_report_terms_and_dominant():
-    r = cell_report(_rec())
-    assert set(("compute_s", "memory_s", "collective_s",
-                "dominant", "useful_flops_ratio", "fits_hbm")) <= set(r)
-    assert r["dominant"] in ("compute", "memory", "collective")
-    assert r["fits_hbm"] is True
-    assert r["hbm_gib_per_device"] == pytest.approx(8.0)
-
-
-def test_model_flops_kinds():
-    train = model_flops("qwen2.5-3b", "train_4k")
-    prefill = model_flops("qwen2.5-3b", "prefill_32k")
-    decode = model_flops("qwen2.5-3b", "decode_32k")
-    assert train == pytest.approx(6 * prefill / 2, rel=1e-6)  # same tokens
-    assert decode < prefill / 1000                            # 1 token/seq
-    # MoE uses ACTIVE params
-    moe_train = model_flops("deepseek-v2-236b", "train_4k")
-    from repro import configs
-    cfg = configs.get_config("deepseek-v2-236b")
-    assert moe_train == pytest.approx(
-        6.0 * cfg.active_param_count() * 4096 * 256)
